@@ -25,6 +25,8 @@ def main() -> None:
                     help="write rows + validation results as JSON")
     args, _ = ap.parse_known_args()
 
+    from repro.common.compile_cache import configure_compile_cache
+    configure_compile_cache()
     from benchmarks import batching, kv_usage, mixed_longprompt, open_loop
     from benchmarks import phase_intensity, policy_sweep, pressure
     from benchmarks import sanitizer_overhead, shared_prefix, slo_tenants

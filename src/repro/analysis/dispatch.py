@@ -12,9 +12,9 @@ first-class, checkable signal:
 * :class:`DispatchSentinel` wraps jitted callables and counts
   compilations per callable.  The primary probe is the wrapped
   function's ``_cache_size()`` (jax's per-callable compile-cache entry
-  count) sampled around each call; when the probe is unavailable (plain
-  callables, older jax) it falls back to tracking distinct duck-typed
-  argument signatures (shape/dtype for array-likes).
+  count) sampled around each call; a plain callable, which has no such
+  probe, falls back to tracking distinct duck-typed argument signatures
+  (shape/dtype for array-likes).
 * A **storm guard** on step-loop callables raises
   :class:`InvariantViolation` (invariant ``"jit_dispatch"``) when
   compile density stays pathological — ≥ ``storm_threshold`` compiles in

@@ -34,7 +34,7 @@ RPR005 metrics-unsurfaced
     see it, so regressions in what it counts ship silently.
 RPR006 jit-in-hot-path
     ``jax.jit(...)`` constructed anywhere but a setup path (module
-    level, ``__init__``, ``_build_*``) creates a *fresh* compile cache
+    level, ``__init__``, ``build_*`` / ``_build_*``) creates a *fresh* compile cache
     per call — every invocation pays a full XLA compile, the exact
     recompile storm the dispatch sentinel (``analysis/dispatch.py``)
     exists to catch at runtime.  Immediately-invoked ``jax.jit(f)(x)``
@@ -338,7 +338,7 @@ def _is_jit(node: ast.expr) -> bool:
     return isinstance(node, ast.Name) and node.id == "jit"
 
 
-_JIT_SETUP_NAMES = ("__init__",)     # plus any function named _build*
+_JIT_SETUP_NAMES = ("__init__",)     # plus any function named [_]build*
 
 
 class JitInHotPath(Rule):
@@ -374,7 +374,8 @@ class JitInHotPath(Rule):
                     self.skip.add(id(node.func))
                 if _is_jit(node.func) and id(node) not in self.skip:
                     in_setup = any(
-                        name in _JIT_SETUP_NAMES or name.startswith("_build")
+                        name in _JIT_SETUP_NAMES
+                        or name.lstrip("_").startswith("build")
                         for name in self.stack)
                     if self.stack and not in_setup:
                         findings.append(Finding(
@@ -382,7 +383,7 @@ class JitInHotPath(Rule):
                             f"jax.jit constructed inside "
                             f"{self.stack[-1]}(): a fresh compile cache per "
                             "call is a recompile storm; hoist to module "
-                            "level, __init__, or a _build_* method"))
+                            "level, __init__, or a [_]build_* function"))
                 self.generic_visit(node)
 
         V().visit(tree)

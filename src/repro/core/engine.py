@@ -76,7 +76,7 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Iterator, List, Optional
+from typing import Callable, Iterator, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +98,137 @@ from repro.core.sampler import SamplingParams, greedy_tokens, sample_tokens
 from repro.core.scheduler import Scheduler
 from repro.core.slo import EffectiveSLO, SLOParams, resolve_slo, slo_outcome
 from repro.models import transformer as T
+
+
+# --------------------------------------------------------- device programs --
+class StepPrograms(NamedTuple):
+    """The jitted device programs an ``Engine`` dispatches.  Built once per
+    engine; compile tests and the chip smoke lower the same programs."""
+    prefill: Callable   # (params, tokens [Bp,S], lens [Bp]) -> (logits, kv)
+    commit: Callable    # (k_pages, v_pages, k_new, v_new, dest) -> pages
+    decode: Callable    # (params, tokens, k_pages, v_pages, bt, lens, active)
+    mixed: Callable     # (params, mb, k_pages, v_pages) -> (p_logits,
+                        #   d_logits, pages, aux)
+
+
+def build_step_programs(cfg, kv_dtype: str) -> StepPrograms:
+    int8 = kv_dtype == "int8"
+
+    # full prefill returning per-row last-token logits; in int8 mode
+    # attention reads fake-quantized K/V so the one-shot path is
+    # numerically identical to the chunked paths, which re-read
+    # earlier chunks from quantized pages (cross-mode bit-identity)
+    def prefill_full(params, tokens, lens):
+        x = T.embed(params, cfg, tokens)
+        B, S, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+        hidden, _, kv = T.forward_hidden(
+            params, cfg, x, positions, collect_kv=True,
+            kv_fake_quant=fake_quant_kv if int8 else None)
+        hl = hidden[jnp.arange(B), jnp.clip(lens - 1, 0, S - 1)]
+        return T.unembed(params, cfg, hl), kv
+    # int8 routing: prefill-commit QUANTIZES (fp paged KV -> codes +
+    # per-(token,head) scale written side by side), decode/mixed
+    # DEQUANTIZE in-kernel via the pluggable attn fns; fp path is the
+    # seed behaviour, byte for byte.
+    attn_decode = int8_decode_attn if int8 else None
+    attn_mixed = ({"decode": int8_decode_attn, "chunk": int8_chunk_attn}
+                  if int8 else None)
+
+    def commit(kpg, vpg, k_new, v_new, dest):
+        # k_new [L, M, ps, KV_p, hd]; dest [M] page ids (trash for pads)
+        if int8:
+            kq, vq = quant_kv(k_new, v_new)
+            return ({"q": kpg["q"].at[:, dest].set(kq["q"]),
+                     "s": kpg["s"].at[:, dest].set(kq["s"])},
+                    {"q": vpg["q"].at[:, dest].set(vq["q"]),
+                     "s": vpg["s"].at[:, dest].set(vq["s"])})
+        return kpg.at[:, dest].set(k_new), vpg.at[:, dest].set(v_new)
+
+    def decode_fn(params, tokens, kpg, vpg, bt, lens, active):
+        return T.decode(params, cfg, tokens, kpg, vpg, bt, lens,
+                        active=active, attn_fn=attn_decode)
+
+    def mixed_fn(params, mb, kpg, vpg):
+        return T.mixed(params, cfg, mb, kpg, vpg, attn_fn=attn_mixed)
+
+    return StepPrograms(
+        prefill=jax.jit(prefill_full),
+        commit=jax.jit(commit, donate_argnums=(0, 1)),
+        decode=jax.jit(decode_fn, donate_argnums=(2, 3)),
+        mixed=jax.jit(mixed_fn, donate_argnums=(2, 3)))
+
+
+def pool_layout(cfg, serve: ServeConfig, dtype):
+    """(n_pages, page_bytes) of the engine's KV pool.
+
+    Byte-denominated: the budget defaults to ``n_pages`` fp-width pages,
+    so flipping kv_dtype="int8" alone holds the pool BYTES constant and
+    grows the page COUNT (codes + f32 scale sidecar are narrower than fp
+    tokens) — the capacity lever."""
+    fp_page_bytes = kv_page_bytes(cfg, serve.page_size, dtype)
+    page_bytes = kv_page_bytes(cfg, serve.page_size, dtype,
+                               kv_dtype=serve.kv_dtype)
+    budget = (serve.kv_pool_bytes if serve.kv_pool_bytes is not None
+              else serve.n_pages * fp_page_bytes)
+    return pool_pages_from_bytes(budget, page_bytes), page_bytes
+
+
+def init_pool(cfg, serve: ServeConfig, n_pages: int, dtype):
+    """Zeroed (k_pages, v_pages) in the layout ``serve.kv_dtype`` names."""
+    if serve.kv_dtype == "int8":
+        return init_pages_int8(cfg, n_pages, serve.page_size)
+    return T.init_pages(cfg, n_pages, serve.page_size, dtype=dtype)
+
+
+def step_program_args(cfg, serve: ServeConfig, params, *, prefill_batch: int,
+                      prefill_len: int) -> dict:
+    """``{name: (program, abstract args)}`` for every program shape an
+    ``Engine(model, params, serve)`` dispatches, with sequential-mode
+    prefill batches of ``prefill_batch`` prompts padded to
+    ``prefill_len`` tokens.  ``params`` may be abstract
+    (``jax.eval_shape`` of ``Model.init``): nothing is allocated, so the
+    programs lower for a chip that is only described.  The shapes mirror
+    ``_prefill_full_batch``, ``_do_decode`` and ``_dispatch_mixed``."""
+    progs = build_step_programs(cfg, serve.kv_dtype)
+    dtype = jax.tree.leaves(params)[0].dtype
+    n_pages, _ = pool_layout(cfg, serve, dtype)
+    kpg, vpg = jax.eval_shape(lambda: init_pool(cfg, serve, n_pages, dtype))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def flags(n):
+        return jax.ShapeDtypeStruct((n,), jnp.bool_)
+
+    B, W, P = serve.max_batch, serve.max_pages_per_seq, serve.n_streams
+    tokens, lens = i32(prefill_batch, prefill_len), i32(prefill_batch)
+    _, (k, _) = jax.eval_shape(progs.prefill, params, tokens, lens)
+    k_new = jax.eval_shape(lambda a: T.kv_to_pages(a, serve.page_size), k)
+
+    def mb(C, n_decode):
+        return dict(p_tokens=i32(P, C), p_table=i32(P, W), p_start=i32(P),
+                    p_lens=i32(P), d_tokens=i32(n_decode),
+                    d_table=i32(n_decode, W), d_lens=i32(n_decode),
+                    d_active=flags(n_decode))
+
+    out = {
+        "prefill": (progs.prefill, (params, tokens, lens)),
+        "commit": (progs.commit, (kpg, vpg, k_new, k_new,
+                                  i32(k_new.shape[1]))),
+        "decode": (progs.decode, (params, i32(B), kpg, vpg, i32(B, W),
+                                  i32(B), flags(B))),
+        # splitwiser_mps: prefill chunks + every decode slot, one program
+        "mixed": (progs.mixed, (params, mb(serve.prefill_chunk, B), kpg, vpg)),
+        # splitwiser: the time-sliced prefill phase, zero-size decode half
+        "mixed_prefill": (progs.mixed, (params, mb(serve.prefill_chunk, 0),
+                                        kpg, vpg)),
+    }
+    if serve.chunk_tokens != serve.prefill_chunk:
+        # chunked: the planner's row width is the chunk_tokens budget
+        out["mixed_chunked"] = (progs.mixed, (params, mb(serve.chunk_tokens, B),
+                                              kpg, vpg))
+    return out
 
 
 @dataclass
@@ -186,17 +317,8 @@ class Engine:
             PrefixCache(serve.page_size,
                         policy=serve.resolved_eviction_policy)
             if serve.enable_prefix_cache else None)
-        # byte-denominated page pool: the budget defaults to n_pages
-        # fp-width pages, so flipping kv_dtype="int8" alone holds the pool
-        # BYTES constant and grows the page COUNT (codes + f32 scale
-        # sidecar are narrower than fp tokens) — the capacity lever.
         dtype = jax.tree.leaves(params)[0].dtype
-        fp_page_bytes = kv_page_bytes(self.cfg, serve.page_size, dtype)
-        page_bytes = kv_page_bytes(self.cfg, serve.page_size, dtype,
-                                   kv_dtype=serve.kv_dtype)
-        budget = (serve.kv_pool_bytes if serve.kv_pool_bytes is not None
-                  else serve.n_pages * fp_page_bytes)
-        n_pages = pool_pages_from_bytes(budget, page_bytes)
+        n_pages, page_bytes = pool_layout(self.cfg, serve, dtype)
         # int8 sidecar mirror: page id -> scale-entry count (host-side
         # shadow of which pool pages hold quantized contents)
         self.kv_quant = (KVQuantSidecar()
@@ -224,12 +346,7 @@ class Engine:
                                      np.int32)
         self.stream_tables = np.zeros((serve.n_streams, serve.max_pages_per_seq),
                                       np.int32)
-        if serve.kv_dtype == "int8":
-            self.k_pages, self.v_pages = init_pages_int8(
-                self.cfg, n_pages, serve.page_size)
-        else:
-            self.k_pages, self.v_pages = T.init_pages(
-                self.cfg, n_pages, serve.page_size, dtype=dtype)
+        self.k_pages, self.v_pages = init_pool(self.cfg, serve, n_pages, dtype)
         self._step_parity = 0
         self._events: List[TokenEvent] = []
         self._outputs: List[RequestOutput] = []
@@ -256,60 +373,16 @@ class Engine:
 
     # ------------------------------------------------------------- jits ----
     def _build_jits(self):
-        cfg = self.cfg
-
-        int8 = self.serve.kv_dtype == "int8"
-
-        # full prefill returning per-row last-token logits; in int8 mode
-        # attention reads fake-quantized K/V so the one-shot path is
-        # numerically identical to the chunked paths, which re-read
-        # earlier chunks from quantized pages (cross-mode bit-identity)
-        def prefill_full(params, tokens, lens):
-            x = T.embed(params, cfg, tokens)
-            B, S, _ = x.shape
-            positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-            hidden, _, kv = T.forward_hidden(
-                params, cfg, x, positions, collect_kv=True,
-                kv_fake_quant=fake_quant_kv if int8 else None)
-            hl = hidden[jnp.arange(B), jnp.clip(lens - 1, 0, S - 1)]
-            return T.unembed(params, cfg, hl), kv
-        # int8 routing: prefill-commit QUANTIZES (fp paged KV -> codes +
-        # per-(token,head) scale written side by side), decode/mixed
-        # DEQUANTIZE in-kernel via the pluggable attn fns; fp path is the
-        # seed behaviour, byte for byte.
-        attn_decode = int8_decode_attn if int8 else None
-        attn_mixed = ({"decode": int8_decode_attn, "chunk": int8_chunk_attn}
-                      if int8 else None)
-
-        def commit(kpg, vpg, k_new, v_new, dest):
-            # k_new [L, M, ps, KV_p, hd]; dest [M] page ids (trash for pads)
-            if int8:
-                kq, vq = quant_kv(k_new, v_new)
-                return ({"q": kpg["q"].at[:, dest].set(kq["q"]),
-                         "s": kpg["s"].at[:, dest].set(kq["s"])},
-                        {"q": vpg["q"].at[:, dest].set(vq["q"]),
-                         "s": vpg["s"].at[:, dest].set(vq["s"])})
-            return kpg.at[:, dest].set(k_new), vpg.at[:, dest].set(v_new)
-
-        def decode_fn(params, tokens, kpg, vpg, bt, lens, active):
-            return T.decode(params, cfg, tokens, kpg, vpg, bt, lens,
-                            active=active, attn_fn=attn_decode)
-
-        def mixed_fn(params, mb, kpg, vpg):
-            return T.mixed(params, cfg, mb, kpg, vpg, attn_fn=attn_mixed)
-
+        progs = build_step_programs(self.cfg, self.serve.kv_dtype)
         # prefill/commit batches legitimately vary with workload shape, so
         # the sentinel only counts them; decode/mixed/samplers are the
         # steady-state step loop where any compile density is a bug.
-        self._prefill = self._sentineled("prefill", jax.jit(prefill_full),
+        self._prefill = self._sentineled("prefill", progs.prefill,
                                          storm_guard=False)
-        self._commit = self._sentineled(
-            "commit", jax.jit(commit, donate_argnums=(0, 1)),
-            storm_guard=False)
-        self._decode = self._sentineled(
-            "decode", jax.jit(decode_fn, donate_argnums=(2, 3)))
-        self._mixed = self._sentineled(
-            "mixed", jax.jit(mixed_fn, donate_argnums=(2, 3)))
+        self._commit = self._sentineled("commit", progs.commit,
+                                        storm_guard=False)
+        self._decode = self._sentineled("decode", progs.decode)
+        self._mixed = self._sentineled("mixed", progs.mixed)
         self._greedy = self._sentineled("sample_greedy", greedy_tokens)
         self._sample = self._sentineled("sample", sample_tokens)
 

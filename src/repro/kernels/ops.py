@@ -1,9 +1,9 @@
 """Jit'd wrappers around the Pallas kernels.
 
-Handle layout adaptation (model convention <-> kernel tiling), head-dim
-padding to the 128-lane VREG width, and automatic interpret-mode on CPU
-(the kernels target TPU; on this container they are validated with
-interpret=True against the ref.py oracles).
+Handle layout adaptation (model convention <-> kernel tiling) and head-dim
+padding to the 128-lane VREG width.  Every caller states ``interpret``:
+True runs the Pallas interpreter (how the CPU tests validate the kernels
+against the ref.py oracles), False hands the kernel to the TPU compiler.
 """
 from __future__ import annotations
 
@@ -19,10 +19,6 @@ from repro.kernels import paged_attention_int8 as _paged_i8_mod
 LANE = 128
 
 
-def _interpret_default():
-    return jax.default_backend() == "cpu"
-
-
 def _pad_d(x, d_pad):
     d = x.shape[-1]
     if d == d_pad:
@@ -32,12 +28,11 @@ def _pad_d(x, d_pad):
 
 @partial(jax.jit, static_argnames=("scale", "window", "softcap", "interpret"))
 def paged_attention(q, k_pages, v_pages, block_table, kv_lens, q_pos, *,
-                    scale, window=None, softcap=None, interpret=None):
+                    scale, window=None, softcap=None, interpret):
     """Model-layout ragged paged attention.
 
     q [B, Tq, H_p, d]; pages [N, ps, KV_p, d]. Returns [B, Tq, H_p, d].
     """
-    interpret = _interpret_default() if interpret is None else interpret
     B, Tq, H_p, d = q.shape
     KV_p = k_pages.shape[2]
     G = H_p // KV_p
@@ -56,7 +51,7 @@ def paged_attention(q, k_pages, v_pages, block_table, kv_lens, q_pos, *,
 @partial(jax.jit, static_argnames=("scale", "window", "softcap", "interpret"))
 def paged_attention_int8(q, k_pages, k_scales, v_pages, v_scales,
                          block_table, kv_lens, q_pos, *,
-                         scale, window=None, softcap=None, interpret=None):
+                         scale, window=None, softcap=None, interpret):
     """Model-layout ragged paged attention over int8 pages.
 
     q [B, Tq, H_p, d] fp; code pages [N, ps, KV_p, d] int8; scale
@@ -64,7 +59,6 @@ def paged_attention_int8(q, k_pages, k_scales, v_pages, v_scales,
     Codes pad with zeros to the 128-lane width — the padded columns
     dequantize to exactly 0 and are sliced off after the kernel.
     """
-    interpret = _interpret_default() if interpret is None else interpret
     B, Tq, H_p, d = q.shape
     KV_p = k_pages.shape[2]
     G = H_p // KV_p
@@ -83,12 +77,11 @@ def paged_attention_int8(q, k_pages, k_scales, v_pages, v_scales,
 @partial(jax.jit, static_argnames=("scale", "causal", "window", "softcap",
                                    "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, kv_lens, *, scale, causal=True, window=None,
-                    softcap=None, block_q=128, block_k=128, interpret=None):
+                    softcap=None, block_q=128, block_k=128, interpret):
     """Model-layout flash attention.
 
     q [B, T, H_p, d]; k/v [B, Tk, KV_p, d]. Returns [B, T, H_p, d].
     """
-    interpret = _interpret_default() if interpret is None else interpret
     B, T, H_p, d = q.shape
     KV_p = k.shape[2]
     G = H_p // KV_p
@@ -105,7 +98,7 @@ def flash_attention(q, k, v, kv_lens, *, scale, causal=True, window=None,
     return o[..., :d]
 
 
-def paged_attn_model_fn(interpret=None):
+def paged_attn_model_fn(interpret):
     """Adapter matching transformer.default_paged_attn's signature."""
     def fn(q, kpg, vpg, block_table, kv_lens, q_positions, *, scale, window,
            attn_softcap):

@@ -13,15 +13,22 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    # The model code places arrays with with_sharding_constraint, which
+    # accepts only Auto axes; make_mesh defaults to Explicit ones.
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh over however many (possibly forced-host) devices exist."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple:

@@ -19,6 +19,7 @@ import json
 import jax
 import numpy as np
 
+from repro.common.compile_cache import configure_compile_cache
 from repro.configs import ServeConfig, get_config
 from repro.core.engine import Engine, Request
 from repro.core.sampler import SamplingParams
@@ -28,12 +29,14 @@ from repro.models.registry import CACHE_KIND, FAMILY_MODULE, Model
 
 def build_engine(arch, mode, *, reduced=True, max_batch=8, page_size=16,
                  n_pages=512, n_streams=2, prefill_chunk=64, seed=0,
-                 max_pages_per_seq=64):
+                 max_pages_per_seq=64, dtype=None):
+    """An ``Engine`` over random ``arch`` weights made from ``seed``, in
+    ``dtype`` (f32 when None); the KV pool takes the same dtype."""
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
     model = Model(arch, cfg, FAMILY_MODULE[cfg.family], CACHE_KIND[cfg.family])
-    params = model.init(jax.random.PRNGKey(seed))
+    params = model.init(jax.random.PRNGKey(seed), dtype)
     serve = ServeConfig(mode=mode, max_batch=max_batch, page_size=page_size,
                         n_pages=n_pages, n_streams=n_streams,
                         prefill_chunk=prefill_chunk,
@@ -64,6 +67,7 @@ def main():
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
 
+    configure_compile_cache()
     engine, cfg = build_engine(
         args.arch, args.mode, reduced=args.reduced, max_batch=args.max_batch,
         n_streams=args.n_streams, prefill_chunk=args.prefill_chunk,

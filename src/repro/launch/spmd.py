@@ -24,10 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:                                    # newer jax
-    from jax import shard_map
+from jax import shard_map
 
 from repro.models.layers import paged_attention_ref, act_fn
 from repro.models.moe import moe_apply
@@ -87,7 +84,7 @@ def make_sharded_decode_attn(mesh, *, data=("data",), model="model",
             in_specs=(q_spec, kn_spec, kn_spec, pspec, pspec, bt_spec,
                       v_spec, v_spec, P()),
             out_specs=(q_spec, pspec, pspec),
-            check_rep=False,
+            check_vma=False,
         )
         return mapped(q, k_new, v_new, kpg, vpg, bt, lens, active, win)
 
@@ -130,7 +127,7 @@ def make_sharded_chunk_attn(mesh, *, data=("data",), model="model",
             in_specs=(q_spec, kn_spec, kn_spec, pspec, pspec, bt_spec,
                       v_spec, v_spec, P()),
             out_specs=(q_spec, pspec, pspec),
-            check_rep=False,
+            check_vma=False,
         )
         return mapped(q, k_new, v_new, kpg, vpg, bt, start, lens, win)
 
@@ -210,6 +207,6 @@ def make_sharded_moe_fn(mesh, cfg, *, tp: int, data=("data",), model="model",
         local, mesh=mesh,
         in_specs=(w_spec, x_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return lambda lp, x2d: mapped(lp, x2d)

@@ -110,7 +110,7 @@ def test_pallas_int8_kernel_matches_jnp_ref():
                                 q_pos[:, None] + jnp.arange(Tq)[None],
                                 scale=0.3, window=None, attn_softcap=None)
     got = ops.paged_attention_int8(q, kq, kscale, vq, vscale, bt, lens,
-                                   q_pos, scale=0.3)
+                                   q_pos, scale=0.3, interpret=True)
     rel = float(jnp.abs(got - want).max()) / float(jnp.abs(want).max())
     assert rel < 1e-5, rel
 

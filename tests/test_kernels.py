@@ -1,5 +1,6 @@
 """Pallas kernel validation: shape/dtype sweeps, assert_allclose vs the
-pure-jnp oracle (ref.py), executed with interpret=True on CPU."""
+pure-jnp oracle (ref.py), executed with interpret=True on CPU.  What the
+TPU compiler accepts is checked in test_chip_compile.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,7 +37,8 @@ def test_paged_attention_vs_ref(case, dtype):
     hist = np.random.RandomState(1).randint(0, Pmax * ps - Tq, size=B)
     q_pos = jnp.asarray(hist, jnp.int32)
     kv_lens = q_pos + Tq
-    out = ops.paged_attention(q, kpg, vpg, bt, kv_lens, q_pos, scale=0.2)
+    out = ops.paged_attention(q, kpg, vpg, bt, kv_lens, q_pos, scale=0.2,
+                              interpret=True)
     G = H // KV
     qk = q.reshape(B, Tq, KV, G, d).transpose(0, 2, 1, 3, 4)
     want = ref.paged_attention_ref(qk, kpg, vpg, bt, kv_lens, q_pos, scale=0.2)
@@ -54,7 +56,7 @@ def test_paged_attention_softcap_window(softcap, window):
     q_pos = jnp.asarray([8, 3], jnp.int32)
     kv_lens = q_pos + Tq
     out = ops.paged_attention(q, kpg, vpg, bt, kv_lens, q_pos, scale=0.2,
-                              softcap=softcap, window=window)
+                              softcap=softcap, window=window, interpret=True)
     G = H // KV
     qk = q.reshape(B, Tq, KV, G, d).transpose(0, 2, 1, 3, 4)
     want = ref.paged_attention_ref(qk, kpg, vpg, bt, kv_lens, q_pos,
@@ -81,7 +83,7 @@ def test_flash_attention_vs_ref(case, dtype):
     v = (jax.random.normal(ks[2], (B, Tk, KV, d)) * 0.5).astype(dtype)
     kv_lens = jnp.asarray([Tk] + [Tk - 5] * (B - 1), jnp.int32)
     out = ops.flash_attention(q, k, v, kv_lens, scale=0.2, block_q=bq,
-                              block_k=bk)
+                              block_k=bk, interpret=True)
     G = H // KV
     qk = q.reshape(B, T, KV, G, d).transpose(0, 2, 1, 3, 4)
     kk = k.transpose(0, 2, 1, 3)
@@ -101,7 +103,8 @@ def test_flash_window_softcap():
     v = jax.random.normal(ks[2], (B, T, KV, d)) * 0.5
     lens = jnp.asarray([T], jnp.int32)
     out = ops.flash_attention(q, k, v, lens, scale=0.2, window=8,
-                              softcap=25.0, block_q=8, block_k=8)
+                              softcap=25.0, block_q=8, block_k=8,
+                              interpret=True)
     qk = q.reshape(B, T, KV, 1, d).transpose(0, 2, 1, 3, 4)
     want = ref.flash_attention_ref(qk, k.transpose(0, 2, 1, 3),
                                    v.transpose(0, 2, 1, 3), lens, scale=0.2,
@@ -119,10 +122,10 @@ def test_paged_kernel_is_splitwiser_unified():
     qc = jax.random.normal(jax.random.PRNGKey(12), (B, 8, H, d)) * 0.5
     lens_dec = jnp.asarray([30, 17], jnp.int32)
     out_dec = ops.paged_attention(q1, kpg, vpg, bt, lens_dec + 1, lens_dec,
-                                  scale=0.2)
+                                  scale=0.2, interpret=True)
     start = jnp.asarray([4, 0], jnp.int32)
     out_chunk = ops.paged_attention(qc, kpg, vpg, bt, start + 8, start,
-                                    scale=0.2)
+                                    scale=0.2, interpret=True)
     assert out_dec.shape == (B, 1, H, d)
     assert out_chunk.shape == (B, 8, H, d)
     assert bool(jnp.isfinite(out_dec).all() and jnp.isfinite(out_chunk).all())

@@ -97,7 +97,8 @@ from repro.launch.shardings import named
 steps.SHAPES['train_4k'] = dict(kind='train', seq=512, batch=8)
 steps.SHAPES['decode_32k'] = dict(kind='decode', seq=1024, batch=8)
 steps.SHAPES['mixed_32k'] = dict(kind='mixed', seq=1024, batch=8, chunk=64, streams=2)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cell, why = steps.build_cell({arch!r}, {shape!r}, mesh)
 assert cell is not None, why
 jitted = jax.jit(cell['fn'], in_shardings=named(mesh, cell['in_shardings']),
