@@ -698,37 +698,45 @@ class Engine:
 
     # ------------------------------------------------------------- steps ---
     def step(self) -> List[TokenEvent]:
+        """One engine step, recorded as an ``engine.step`` span (attributes
+        ``kind`` and ``kv_usage``) whose children are its phases:
+        ``engine.schedule``, ``engine.plan``, ``engine.compose``,
+        ``engine.dispatch``, ``engine.sync`` (the host waiting on the
+        device) and ``engine.advance``, and any ``host.gc``."""
         self._events = []
         mode = self.serve.mode
         n_pre = self.metrics.n_preempt_events
-        if mode == "sequential":
-            kind = self._step_sequential()
-        elif mode == "splitwiser":
-            kind = self._step_timesliced()
-        elif mode == "splitwiser_mps":
-            kind = self._step_fused()
-        elif mode == "chunked":
-            kind = self._step_chunked()
-        else:
-            # ServeConfig.__post_init__ validates against SERVE_MODES, so
-            # reaching here means a mode was registered without a step path
-            raise RuntimeError(
-                f"no step path for serve mode {mode!r}; SERVE_MODES and "
-                "Engine.step() must be extended together")
-        if kind == "idle" and self.metrics.n_preempt_events > n_pre:
-            kind = "preempt"    # nothing dispatched, but evictions happened
-        self.metrics.n_steps += 1
-        self.metrics.step_kinds.append(kind)
-        self.metrics.kv_usage_trace.append(self.alloc.usage())
-        self._refresh_cache_stats()
-        if self.sanitizer is not None:
-            self.sanitizer.after_step(
-                any(e.finish_reason is not None for e in self._events))
+        with self.metrics.span("engine.step") as step_span:
+            if mode == "sequential":
+                kind = self._step_sequential()
+            elif mode == "splitwiser":
+                kind = self._step_timesliced()
+            elif mode == "splitwiser_mps":
+                kind = self._step_fused()
+            elif mode == "chunked":
+                kind = self._step_chunked()
+            else:
+                # ServeConfig.__post_init__ validates against SERVE_MODES, so
+                # reaching here means a mode was registered without a step path
+                raise RuntimeError(
+                    f"no step path for serve mode {mode!r}; SERVE_MODES and "
+                    "Engine.step() must be extended together")
+            if kind == "idle" and self.metrics.n_preempt_events > n_pre:
+                kind = "preempt"    # nothing dispatched, but evictions happened
+            with self.metrics.span("engine.advance"):
+                usage = self.alloc.usage()
+                self.metrics.note_step(usage)
+                self._refresh_cache_stats()
+                if self.sanitizer is not None:
+                    self.sanitizer.after_step(
+                        any(e.finish_reason is not None for e in self._events))
+            step_span.attrs = {"kind": kind, "kv_usage": usage}
         return self._events
 
     # --- sequential: full-prompt prefill OR decode per step -----------------
     def _step_sequential(self) -> str:
-        batch = self.sched.take_prefillable()
+        with self.metrics.span("engine.schedule"):
+            batch = self.sched.take_prefillable()
         if batch:
             self._do_full_prefill(batch)
             return "prefill"
@@ -747,9 +755,10 @@ class Engine:
             self._prefill_full_batch(reqs)
             return
         hits, misses = [], []
-        for r in reqs:
-            n_cached = self._map_cached(r)
-            (hits if n_cached else misses).append((r, n_cached))
+        with self.metrics.span("engine.schedule"):
+            for r in reqs:
+                n_cached = self._map_cached(r)
+                (hits if n_cached else misses).append((r, n_cached))
         if misses:
             self._prefill_full_batch([r for r, _ in misses])
         if hits:
@@ -757,39 +766,44 @@ class Engine:
 
     def _prefill_full_batch(self, reqs: List[Request]):
         ps = self.serve.page_size
+        span = self.metrics.span
         t0 = self.now()
-        S_pad = max(-(-max(len(r.prefill_tokens) for r in reqs) // ps) * ps, ps)
-        Bp = len(reqs)
-        tokens = np.zeros((Bp, S_pad), np.int32)
-        lens = np.zeros((Bp,), np.int32)
-        for i, r in enumerate(reqs):
-            toks = r.prefill_tokens
-            tokens[i, : len(toks)] = toks
-            lens[i] = len(toks)
-            m = self.metrics.req(r.rid)
-            if m.t_prefill_start is None:
-                m.t_prefill_start = t0
-            self.metrics.n_prefill_tokens += len(toks)
-        logits, (k, v) = self._prefill(self.params, jnp.asarray(tokens),
-                                       jnp.asarray(lens))
-        # commit contiguous KV into allocated pages
-        n_per = S_pad // ps
-        dest = np.full((Bp * n_per,), self.alloc.trash_page, np.int32)
-        for i, r in enumerate(reqs):
-            pages = self.alloc.alloc(r.rid, self.alloc.pages_needed(lens[i]))
-            dest[i * n_per : i * n_per + len(pages)] = pages
-        k_new = T.kv_to_pages(k, ps)
-        v_new = T.kv_to_pages(v, ps)
-        self.k_pages, self.v_pages = self._commit(
-            self.k_pages, self.v_pages, k_new, v_new, jnp.asarray(dest))
+        with span("engine.compose"):
+            S_pad = max(-(-max(len(r.prefill_tokens) for r in reqs) // ps) * ps,
+                        ps)
+            Bp = len(reqs)
+            tokens = np.zeros((Bp, S_pad), np.int32)
+            lens = np.zeros((Bp,), np.int32)
+            for i, r in enumerate(reqs):
+                toks = r.prefill_tokens
+                tokens[i, : len(toks)] = toks
+                lens[i] = len(toks)
+                m = self.metrics.req(r.rid)
+                if m.t_prefill_start is None:
+                    m.t_prefill_start = t0
+                self.metrics.n_prefill_tokens += len(toks)
+        with span("engine.dispatch", {"program": "prefill"}):
+            logits, (k, v) = self._prefill(self.params, jnp.asarray(tokens),
+                                           jnp.asarray(lens))
+            # commit contiguous KV into allocated pages
+            n_per = S_pad // ps
+            dest = np.full((Bp * n_per,), self.alloc.trash_page, np.int32)
+            for i, r in enumerate(reqs):
+                pages = self.alloc.alloc(r.rid, self.alloc.pages_needed(lens[i]))
+                dest[i * n_per : i * n_per + len(pages)] = pages
+            k_new = T.kv_to_pages(k, ps)
+            v_new = T.kv_to_pages(v, ps)
+            self.k_pages, self.v_pages = self._commit(
+                self.k_pages, self.v_pages, k_new, v_new, jnp.asarray(dest))
         if self.kv_quant is not None:
             for r in reqs:       # before _emit_first_token may free them
                 self.kv_quant.note_write(self.alloc.owned(r.rid))
         toks = self._sample_rows(logits, reqs)
         t1 = self.now()
-        for i, r in enumerate(reqs):
-            self.cache_insert(r, int(lens[i]))
-            self._emit_first_token(r, int(toks[i]), int(lens[i]), t1)
+        with span("engine.advance"):
+            for i, r in enumerate(reqs):
+                self.cache_insert(r, int(lens[i]))
+                self._emit_first_token(r, int(toks[i]), int(lens[i]), t1)
 
     def _prefill_suffix_batch(self, hits: List[tuple]):
         """Prefill (request, n_cached) pairs from their first uncached
@@ -799,50 +813,56 @@ class Engine:
         mid-page, inside the COW-copied donor), and only suffix pages
         are freshly allocated."""
         ps = self.serve.page_size
+        span = self.metrics.span
         t0 = self.now()
         P = len(hits)
         suffixes = [r.prefill_tokens[n:] for r, n in hits]
         C = max(-(-max(len(s) for s in suffixes) // ps) * ps, ps)
         W = self.serve.max_pages_per_seq + 1   # +1 slack: padded chunk page
                                                # lookups may peek one past
-        p_tokens = np.zeros((P, C), np.int32)
-        p_start = np.zeros((P,), np.int32)
-        p_lens = np.zeros((P,), np.int32)
-        p_table = np.zeros((P, W), np.int32)
-        for i, (r, n) in enumerate(hits):
-            toks = suffixes[i]
-            m = self.metrics.req(r.rid)
-            if m.t_prefill_start is None:
-                m.t_prefill_start = t0
-            self.alloc.extend_to(r.rid, n + len(toks))
-            self._apply_cow(self.alloc.prepare_write(r.rid, n, len(toks)))
-            bt = self.alloc.owned(r.rid)
-            p_table[i, : len(bt)] = bt
-            p_tokens[i, : len(toks)] = toks
-            p_start[i] = n
-            p_lens[i] = len(toks)
-            self.metrics.n_prefill_tokens += len(toks)
-        mb = dict(
-            p_tokens=jnp.asarray(p_tokens),
-            p_table=jnp.asarray(p_table),
-            p_start=jnp.asarray(p_start),
-            p_lens=jnp.asarray(p_lens),
-            d_tokens=jnp.zeros((0,), jnp.int32),
-            d_table=jnp.zeros((0, W), jnp.int32),
-            d_lens=jnp.zeros((0,), jnp.int32),
-            d_active=jnp.zeros((0,), bool),
-        )
-        p_logits, _, (self.k_pages, self.v_pages), _ = self._mixed(
-            self.params, mb, self.k_pages, self.v_pages)
+        with span("engine.compose"):
+            p_tokens = np.zeros((P, C), np.int32)
+            p_start = np.zeros((P,), np.int32)
+            p_lens = np.zeros((P,), np.int32)
+            p_table = np.zeros((P, W), np.int32)
+            for i, (r, n) in enumerate(hits):
+                toks = suffixes[i]
+                m = self.metrics.req(r.rid)
+                if m.t_prefill_start is None:
+                    m.t_prefill_start = t0
+                self.alloc.extend_to(r.rid, n + len(toks))
+                self._apply_cow(self.alloc.prepare_write(r.rid, n, len(toks)))
+                bt = self.alloc.owned(r.rid)
+                p_table[i, : len(bt)] = bt
+                p_tokens[i, : len(toks)] = toks
+                p_start[i] = n
+                p_lens[i] = len(toks)
+                self.metrics.n_prefill_tokens += len(toks)
+        with span("engine.dispatch", self._mixed_rows(
+                [(n, len(sfx)) for (_, n), sfx in zip(hits, suffixes)],
+                [r.rid for r, _ in hits], [], [], P * C)):
+            mb = dict(
+                p_tokens=jnp.asarray(p_tokens),
+                p_table=jnp.asarray(p_table),
+                p_start=jnp.asarray(p_start),
+                p_lens=jnp.asarray(p_lens),
+                d_tokens=jnp.zeros((0,), jnp.int32),
+                d_table=jnp.zeros((0, W), jnp.int32),
+                d_lens=jnp.zeros((0,), jnp.int32),
+                d_active=jnp.zeros((0,), bool),
+            )
+            p_logits, _, (self.k_pages, self.v_pages), _ = self._mixed(
+                self.params, mb, self.k_pages, self.v_pages)
         if self.kv_quant is not None:
             for r, _ in hits:    # hit pages were written by their donor;
                 self.kv_quant.note_write(self.alloc.owned(r.rid))  # idempotent
         toks_out = self._sample_rows(p_logits, [r for r, _ in hits])
         t1 = self.now()
-        for i, (r, n) in enumerate(hits):
-            full_len = n + len(suffixes[i])
-            self.cache_insert(r, full_len)
-            self._emit_first_token(r, int(toks_out[i]), full_len, t1)
+        with span("engine.advance"):
+            for i, (r, n) in enumerate(hits):
+                full_len = n + len(suffixes[i])
+                self.cache_insert(r, full_len)
+                self._emit_first_token(r, int(toks_out[i]), full_len, t1)
 
     def _emit_first_token(self, req: Request, tok: int, seq_len: int, t):
         """First token after a (re-)prefill; a resumed request keeps its
@@ -938,19 +958,28 @@ class Engine:
                 self.block_tables[i, : len(bt)] = bt
 
     def _do_decode(self) -> bool:
-        self._reserve_decode_pages()
-        tokens, lens, active = self._decode_inputs()
+        span = self.metrics.span
+        with span("engine.schedule"):
+            self._reserve_decode_pages()
+        with span("engine.compose"):
+            tokens, lens, active = self._decode_inputs()
         if not active.any():        # every slot was preempted
             return False
-        logits, (self.k_pages, self.v_pages) = self._decode(
-            self.params, jnp.asarray(tokens), self.k_pages, self.v_pages,
-            jnp.asarray(self.block_tables), jnp.asarray(lens),
-            jnp.asarray(active))
-        self._advance_decode(logits, active, self.now())
+        with span("engine.dispatch", {"program": "decode"}):
+            logits, (self.k_pages, self.v_pages) = self._decode(
+                self.params, jnp.asarray(tokens), self.k_pages, self.v_pages,
+                jnp.asarray(self.block_tables), jnp.asarray(lens),
+                jnp.asarray(active))
+        toks = self._sample_rows(logits, self._decode_rows(active))
+        t = self.now()
+        with span("engine.advance"):
+            self._advance_decode(toks, active, t)
         return True
 
     # --- splitwiser modes ----------------------------------------------------
     def _refill_streams(self):
+        """Admit waiting requests into free prefill streams (the caller
+        holds the ``engine.schedule`` span)."""
         for r in self.sched.admit_streams():
             i = self.streams.index(None)
             # a cache hit maps shared pages and fast-forwards the stream
@@ -1040,13 +1069,17 @@ class Engine:
                       and self.prefix_cache.is_cached(p)))
         return fresh + cow
 
-    def _advance_streams(self, chunks, p_logits, t):
+    def _completing(self, chunks) -> List[Optional[Request]]:
+        """Per stream row: the request whose prefill a chunk completes."""
         completing = [None] * len(self.streams)
         for i, st, n in chunks:
             if st.pos + n >= len(st.tokens):
                 completing[i] = st.req
-        toks = (self._sample_rows(p_logits, completing)
-                if any(r is not None for r in completing) else None)
+        return completing
+
+    def _advance_streams(self, chunks, toks, t):
+        """Move each chunk's stream on; ``toks`` holds the sampled first
+        token of every completing row (None when no chunk completes)."""
         for i, st, n in chunks:
             st.pos += n
             self.metrics.n_prefill_tokens += n
@@ -1073,70 +1106,104 @@ class Engine:
             active[i] = True
         return tokens, lens, active
 
-    def _dispatch_mixed(self, composed, with_decode: bool) -> bool:
-        """Dispatch ONE mixed program over the composed prefill chunks
-        and advance both halves on a single timestamp — the shared tail
-        of the fused, time-sliced, and chunked step paths.
+    def _mixed_rows(self, chunks, rids, decodes, decode_rids,
+                    rows_program: int) -> dict:
+        """Attributes of a mixed ``engine.dispatch`` span, and the running
+        row totals: the served chunks as ``(start, n)``, the decode rows'
+        sequence lengths, the rids of both (chunks first), the live rows
+        (chunk tokens + decode rows) and the rows of the program's static
+        shape."""
+        live = sum(n for _, n in chunks) + len(decodes)
+        self.metrics.rows_live += live
+        self.metrics.rows_program += rows_program
+        return {"program": "mixed", "rows_live": live,
+                "rows_program": rows_program, "chunks": chunks,
+                "decodes": decodes, "rids": rids + decode_rids}
+
+    def _dispatch_mixed(self, plan: Optional[ChunkPlan],
+                        with_decode: bool) -> Optional[list]:
+        """Compose the prefill chunks (``plan``'s, or one ``prefill_chunk``
+        per stream), dispatch ONE mixed program over them, and advance
+        both halves on a single timestamp taken after the host has the
+        sampled tokens — the shared tail of the fused, time-sliced, and
+        chunked step paths.
 
         ``with_decode=True`` (fused/chunked) packs every decode slot in:
         the decode arrays stay ``max_batch``-sized even when no slot is
         active, so the mode keeps one static program shape.
         ``with_decode=False`` (the time-sliced prefill phase) dispatches
         the same kernel phase-exclusively with zero-size decode arrays.
-        Returns False when there was nothing to dispatch.
+        Returns the dispatched chunks, or None when there was nothing to
+        dispatch.
         """
-        p_tokens, p_start, p_lens, chunks = composed
-        if with_decode:
-            d_tokens, d_lens, d_active = self._decode_inputs()
-            if not chunks and not d_active.any():
-                return False
-            d_half = dict(
-                d_tokens=jnp.asarray(d_tokens),
-                d_table=jnp.asarray(self.block_tables),
-                d_lens=jnp.asarray(d_lens),
-                d_active=jnp.asarray(d_active),
+        span = self.metrics.span
+        with span("engine.compose"):
+            p_tokens, p_start, p_lens, chunks = self._compose_prefill(plan)
+            if with_decode:
+                d_tokens, d_lens, d_active = self._decode_inputs()
+            else:
+                d_active = np.zeros((0,), bool)
+        if not chunks and not d_active.any():
+            return None
+        decoding = [s for s, on in zip(self.slots, d_active) if on]
+        with span("engine.dispatch", self._mixed_rows(
+                [(st.pos, n) for _, st, n in chunks],
+                [st.req.rid for _, st, _ in chunks],
+                [s.seq_len for s in decoding], [s.req.rid for s in decoding],
+                p_tokens.size + d_active.size)):
+            if with_decode:
+                d_half = dict(
+                    d_tokens=jnp.asarray(d_tokens),
+                    d_table=jnp.asarray(self.block_tables),
+                    d_lens=jnp.asarray(d_lens),
+                    d_active=jnp.asarray(d_active),
+                )
+            else:
+                Pmax = self.serve.max_pages_per_seq
+                d_half = dict(
+                    d_tokens=jnp.zeros((0,), jnp.int32),
+                    d_table=jnp.zeros((0, Pmax), jnp.int32),
+                    d_lens=jnp.zeros((0,), jnp.int32),
+                    d_active=jnp.zeros((0,), bool),
+                )
+            mb = dict(
+                p_tokens=jnp.asarray(p_tokens),
+                p_table=jnp.asarray(self.stream_tables),
+                p_start=jnp.asarray(p_start),
+                p_lens=jnp.asarray(p_lens),
+                **d_half,
             )
-        else:
-            if not chunks:
-                return False
-            Pmax = self.serve.max_pages_per_seq
-            d_active = np.zeros((0,), bool)
-            d_half = dict(
-                d_tokens=jnp.zeros((0,), jnp.int32),
-                d_table=jnp.zeros((0, Pmax), jnp.int32),
-                d_lens=jnp.zeros((0,), jnp.int32),
-                d_active=jnp.zeros((0,), bool),
-            )
-        mb = dict(
-            p_tokens=jnp.asarray(p_tokens),
-            p_table=jnp.asarray(self.stream_tables),
-            p_start=jnp.asarray(p_start),
-            p_lens=jnp.asarray(p_lens),
-            **d_half,
-        )
-        p_logits, d_logits, (self.k_pages, self.v_pages), _ = self._mixed(
-            self.params, mb, self.k_pages, self.v_pages)
+            p_logits, d_logits, (self.k_pages, self.v_pages), _ = self._mixed(
+                self.params, mb, self.k_pages, self.v_pages)
+        d_toks = (self._sample_rows(d_logits, self._decode_rows(d_active))
+                  if decoding else None)
+        completing = self._completing(chunks)
+        p_toks = (self._sample_rows(p_logits, completing)
+                  if any(r is not None for r in completing) else None)
         t = self.now()
-        if d_active.size and d_active.any():
-            self._advance_decode(d_logits, d_active, t)
-        self._advance_streams(chunks, p_logits, t)
-        return True
+        with span("engine.advance"):
+            if decoding:
+                self._advance_decode(d_toks, d_active, t)
+            self._advance_streams(chunks, p_toks, t)
+        return chunks
 
     def _step_fused(self) -> str:
         """splitwiser_mps: ONE program runs both phases (the contribution)."""
-        self._refill_streams()
-        # reserve decode pages BEFORE composing prefill: compose-time
-        # eviction of an already-extended slot is safe (it just drops out
-        # of the decode half), the reverse would dispatch a chunk into a
-        # preempted stream's freed pages.
-        self._reserve_decode_pages()
-        if self._dispatch_mixed(self._compose_prefill(), with_decode=True):
+        with self.metrics.span("engine.schedule"):
+            self._refill_streams()
+            # reserve decode pages BEFORE composing prefill: compose-time
+            # eviction of an already-extended slot is safe (it just drops
+            # out of the decode half), the reverse would dispatch a chunk
+            # into a preempted stream's freed pages.
+            self._reserve_decode_pages()
+        if self._dispatch_mixed(None, with_decode=True) is not None:
             return "mixed"
         return "idle"
 
     def _step_timesliced(self) -> str:
         """splitwiser (no MPS): phases alternate as separate programs."""
-        self._refill_streams()
+        with self.metrics.span("engine.schedule"):
+            self._refill_streams()
         has_chunks = any(s is not None and s.pos < len(s.tokens)
                          for s in self.streams)
         has_decode = any(self.slots)
@@ -1145,8 +1212,8 @@ class Engine:
         # phase-exclusive program: prefill chunks only (B=0 decode part);
         # when slot backpressure / page pressure filtered out every chunk,
         # don't dispatch an empty program — fall through to decode
-        if do_prefill and self._dispatch_mixed(self._compose_prefill(),
-                                               with_decode=False):
+        if do_prefill and self._dispatch_mixed(
+                None, with_decode=False) is not None:
             return "prefill_chunk"
         if has_decode and self._do_decode():
             return "decode"
@@ -1161,19 +1228,21 @@ class Engine:
         mixed dispatch per round — a 2k-token prompt becomes a train of
         budget-bounded chunks interleaved with live decodes, so tail TBT
         is bounded by the chunk budget instead of the prompt length."""
-        self._refill_streams()
-        self._reserve_decode_pages()
-        n_decode = sum(s is not None for s in self.slots)
-        remaining = [0 if st is None else max(len(st.tokens) - st.pos, 0)
-                     for st in self.streams]
-        plan = self.planner.plan(remaining, n_decode,
-                                 self._stream_priorities())
-        if self.sanitizer is not None:
-            self.sanitizer.note_plan(plan, remaining, n_decode)
-        composed = self._compose_prefill(plan)
-        if not self._dispatch_mixed(composed, with_decode=True):
+        span = self.metrics.span
+        with span("engine.schedule"):
+            self._refill_streams()
+            self._reserve_decode_pages()
+        with span("engine.plan"):
+            n_decode = sum(s is not None for s in self.slots)
+            remaining = [0 if st is None else max(len(st.tokens) - st.pos, 0)
+                         for st in self.streams]
+            plan = self.planner.plan(remaining, n_decode,
+                                     self._stream_priorities())
+            if self.sanitizer is not None:
+                self.sanitizer.note_plan(plan, remaining, n_decode)
+        chunks = self._dispatch_mixed(plan, with_decode=True)
+        if chunks is None:
             return "idle"
-        chunks = composed[3]
         self.metrics.n_chunks += len(chunks)
         packed = sum(n for _, _, n in chunks) + n_decode
         hist = self.metrics.packed_tokens_hist
@@ -1203,12 +1272,13 @@ class Engine:
             out.append(slack / e.weight if slack >= 0 else slack * e.weight)
         return out
 
-    def _advance_decode(self, d_logits, d_active, t):
-        rows = [s.req if (s is not None and d_active[i]) else None
+    def _decode_rows(self, d_active) -> List[Optional[Request]]:
+        """Per decode row: its request if the row is active, else None."""
+        return [s.req if (s is not None and d_active[i]) else None
                 for i, s in enumerate(self.slots)]
-        if not any(r is not None for r in rows):
-            return
-        toks = self._sample_rows(d_logits, rows)
+
+    def _advance_decode(self, toks, d_active, t):
+        """Append each active slot's sampled token (``toks``, per row)."""
         for i, s in enumerate(self.slots):
             if s is None or not d_active[i]:
                 continue
@@ -1240,7 +1310,9 @@ class Engine:
         being sampled — so results don't depend on batch composition,
         engine mode, or preemption history."""
         if all(r is None or r.sampling.temperature <= 0.0 for r in reqs):
-            return np.asarray(self._greedy(logits))    # all-greedy fast path
+            out = self._greedy(logits)                 # all-greedy fast path
+            with self.metrics.span("engine.sync"):
+                return np.asarray(out)
         B = logits.shape[0]
         temp = np.zeros((B,), np.float32)
         top_k = np.zeros((B,), np.int32)
@@ -1258,6 +1330,8 @@ class Engine:
             seed[i] = sp.seed
             rid[i] = r.rid
             pos[i] = len(r.out_tokens)
-        return np.asarray(self._sample(
+        out = self._sample(
             logits, jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-            jnp.asarray(seed), jnp.asarray(rid), jnp.asarray(pos)))
+            jnp.asarray(seed), jnp.asarray(rid), jnp.asarray(pos))
+        with self.metrics.span("engine.sync"):
+            return np.asarray(out)

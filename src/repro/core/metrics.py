@@ -1,17 +1,26 @@
 """The paper's performance metrics (§II-E): E2E latency, TTFT, TBT,
-throughput, plus KV-cache usage traces (Fig. 5/14/15)."""
+throughput, plus KV-cache usage (Fig. 5/14/15), and the spans of the
+engine's host loop."""
 from __future__ import annotations
 
+import gc
 import statistics
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 DEFAULT_SCHED_EVENTS_CAP = 16384
+# about 9 spans a step (engine.step and its phases): 65,536 hold about
+# 7,000 steps, six times a 45 s window at ~10 steps/s plus a 60 s drain
+DEFAULT_SPANS_CAP = 1 << 16
 
 
 class EventRing:
-    """Bounded scheduler-event trace (list-like, oldest-first).
+    """Bounded event trace (list-like, oldest-first): scheduler events,
+    and the engine's spans.
 
     Long open-loop runs emit an admit/preempt/reclaim event stream that
     previously grew without bound; this ring keeps the newest ``cap``
@@ -27,7 +36,7 @@ class EventRing:
         self._buf: deque = deque(maxlen=cap)
         self.n_dropped = 0
 
-    def append(self, event: dict) -> None:
+    def append(self, event) -> None:
         if len(self._buf) == self.cap:
             self.n_dropped += 1
         self._buf.append(event)
@@ -38,7 +47,7 @@ class EventRing:
         for "what arrived since" bookkeeping that survives drops."""
         return len(self._buf) + self.n_dropped
 
-    def __iter__(self) -> Iterator[dict]:
+    def __iter__(self) -> Iterator:
         return iter(self._buf)
 
     def __len__(self) -> int:
@@ -55,6 +64,76 @@ class EventRing:
     def __repr__(self) -> str:
         return (f"EventRing(cap={self.cap}, n={len(self._buf)}, "
                 f"dropped={self.n_dropped})")
+
+
+class Span:
+    """One timed region of the engine's host loop (choosing-metrics §4):
+    its name, start and end on ``time.perf_counter()``, the id of the
+    span it opened inside (``parent``, None at the top), the engine step
+    it belongs to, and its attributes (None or a dict).
+
+    Opened with ``EngineMetrics.span(name)`` as a context manager; it is
+    also a ``jax.profiler.TraceAnnotation``, so a profiled run holds it
+    on the host plane, on the device trace's clock.  It lands in
+    ``EngineMetrics.spans`` when it closes, so a parent follows its
+    children there."""
+
+    __slots__ = ("name", "sid", "parent", "step", "t0", "t1", "attrs",
+                 "_owner", "_ann")
+
+    def __init__(self, owner: "EngineMetrics", name: str,
+                 attrs: Optional[dict] = None):
+        self.name = name
+        self.attrs = attrs
+        self._owner = owner
+
+    @property
+    def dur(self) -> float:
+        return self.t1 - self.t0
+
+    def __enter__(self) -> "Span":
+        m = self._owner
+        stack = m._open
+        if stack:
+            top = stack[-1]
+            self.parent, self.step = top.sid, top.step
+        else:
+            self.parent, self.step = None, m.n_steps
+            gc.callbacks.append(m._gc_hook)     # collections inside are spans
+        self.sid = m._next_sid
+        m._next_sid += 1
+        stack.append(self)
+        # the annotation only where a profiler listens: spans never
+        # straddle its start or stop, which fall between engine steps
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        m, self._owner = self._owner, None
+        m._open.pop()
+        if not m._open:
+            gc.callbacks.remove(m._gc_hook)
+        m.spans.append(self)
+
+
+def self_time(spans) -> Dict[int, float]:
+    """sid -> a span's length less the lengths of its direct children
+    (the span ring's records, any order; a child whose parent was
+    dropped from the ring is left out)."""
+    own = {s.sid: s.dur for s in spans}
+    for s in spans:
+        if s.parent in own:
+            own[s.parent] -= s.dur
+    return own
 
 
 @dataclass
@@ -108,8 +187,6 @@ class RequestMetrics:
 @dataclass
 class EngineMetrics:
     requests: Dict[int, RequestMetrics] = field(default_factory=dict)
-    kv_usage_trace: List[float] = field(default_factory=list)
-    step_kinds: List[str] = field(default_factory=list)
     # scheduler-event trace: dicts {"t", "event": "admit"|"preempt", "rid",
     # ...}; bounded ring (ServeConfig.sched_events_cap), oldest dropped
     sched_events: EventRing = field(default_factory=EventRing)
@@ -145,6 +222,74 @@ class EngineMetrics:
     # --- SLO outcomes (finished requests carrying a deadline only) ---
     slo_attained: int = 0
     slo_missed: int = 0
+    # --- host-loop spans (Span): engine.step with attributes kind and
+    # kv_usage, its phases, host.gc; bounded ring, oldest dropped ---
+    spans: EventRing = field(
+        default_factory=lambda: EventRing(DEFAULT_SPANS_CAP))
+    # KV pool usage after each step, as running aggregates
+    kv_usage_peak: float = 0.0
+    kv_usage_sum: float = 0.0
+    # rows of the mixed program: live (prefill tokens of the composed
+    # chunks + active decode rows) against its static shape, summed over
+    # every mixed dispatch
+    rows_live: int = 0
+    rows_program: int = 0
+
+    def __post_init__(self):
+        # the span recorder's own state, not telemetry
+        self._open: List[Span] = []     # spans open now, innermost last
+        self._next_sid = 0
+        self._gc_t0 = 0.0
+        self._gc_ann: Optional[TraceAnnotation] = None
+        self._gc_hook = self._on_gc     # one bound method: add and remove
+
+    def span(self, name: str, attrs: Optional[dict] = None) -> Span:
+        """A span to open with ``with``; nests under the open one."""
+        return Span(self, name, attrs)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook, installed while a span is open: each
+        collection becomes a ``host.gc`` span under the innermost open
+        span."""
+        if phase == "start":
+            if TraceAnnotation.is_enabled():
+                self._gc_ann = TraceAnnotation("host.gc")
+                self._gc_ann.__enter__()
+            self._gc_t0 = time.perf_counter()
+            return
+        t1 = time.perf_counter()
+        if self._gc_ann is not None:
+            self._gc_ann.__exit__(None, None, None)
+            self._gc_ann = None
+        top = self._open[-1]
+        s = Span(None, "host.gc", {"generation": info["generation"],
+                                   "collected": info["collected"]})
+        s.sid, s.parent, s.step = self._next_sid, top.sid, top.step
+        s.t0, s.t1 = self._gc_t0, t1
+        self._next_sid += 1
+        self.spans.append(s)
+
+    def note_step(self, kv_usage: float) -> None:
+        """Close the bookkeeping of one engine step."""
+        self.n_steps += 1
+        self.kv_usage_peak = max(self.kv_usage_peak, kv_usage)
+        self.kv_usage_sum += kv_usage
+
+    def _step_attr(self, key: str) -> list:
+        return [s.attrs[key] for s in self.spans
+                if s.name == "engine.step" and s.attrs]
+
+    @property
+    def step_kinds(self) -> List[str]:
+        """Kind of each step, oldest first: a read-only view of the
+        ``engine.step`` spans the ring still holds."""
+        return self._step_attr("kind")
+
+    @property
+    def kv_usage_trace(self) -> List[float]:
+        """KV pool usage after each step, oldest first: a read-only view
+        of the ``engine.step`` spans the ring still holds."""
+        return self._step_attr("kv_usage")
 
     def req(self, rid: int) -> RequestMetrics:
         if rid not in self.requests:
@@ -184,9 +329,9 @@ class EngineMetrics:
                 reason: sum(1 for r in done if r.finish_reason == reason)
                 for reason in sorted({r.finish_reason for r in done
                                       if r.finish_reason is not None})},
-            "kv_usage_peak": max(self.kv_usage_trace, default=0.0),
-            "kv_usage_mean": (sum(self.kv_usage_trace) / len(self.kv_usage_trace))
-                             if self.kv_usage_trace else 0.0,
+            "kv_usage_peak": self.kv_usage_peak,
+            "kv_usage_mean": (self.kv_usage_sum / self.n_steps
+                              if self.n_steps else 0.0),
             "prefill_tokens_computed": self.n_prefill_tokens,
             "cached_tokens": self.n_cached_tokens,
             # fraction of all prefill work served from the prefix cache
@@ -212,6 +357,10 @@ class EngineMetrics:
                    * max(sum(self.packed_tokens_hist.values()), 1))
                 if self.chunk_budget else None),
             "packed_tokens_hist": dict(sorted(self.packed_tokens_hist.items())),
+            # live rows of the mixed program over the rows it computes
+            "row_use": (self.rows_live / self.rows_program
+                        if self.rows_program else None),
+            "spans_dropped": self.spans.n_dropped,
             # SLO outcomes: only requests carrying a deadline count, so
             # attainment is None (not a vacuous 1.0) on deadline-free runs
             "slo_attained": self.slo_attained,

@@ -18,7 +18,7 @@ class TokenEvent:
     rid: int
     token: int
     index: int                    # position in the request's output (0-based)
-    t: float                      # engine-clock timestamp of emission
+    t: float                      # engine-clock time the host held the token
     first: bool                   # True for the request's very first token
     finish_reason: Optional[str] = None   # "length" | "stop" on the last token
 

@@ -79,7 +79,8 @@ def greedy_tokens(logits):
     """Fast path for all-greedy batches (the serving hot path): plain
     argmax, skipping the sort/softmax/categorical machinery entirely.
     Bit-identical to sample_tokens rows with temperature <= 0."""
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def _row_key(seed, rid, pos):
@@ -96,23 +97,25 @@ def sample_tokens(logits, temperature, top_k, top_p, seed, rid, pos):
     greedy engine); the rest are temperature-scaled, top-k- then
     top-p-masked, and sampled from their ``(seed, rid, pos)`` stream.
     """
-    V = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        V = logits.shape[-1]
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    lg = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    # top-k: mask strictly below each row's k-th largest logit (k=0 -> off)
-    sorted_lg = jnp.sort(lg, axis=-1)[:, ::-1]
-    kth = jnp.take_along_axis(sorted_lg, jnp.clip(top_k - 1, 0, V - 1)[:, None],
-                              axis=-1)
-    lg = jnp.where((top_k[:, None] > 0) & (lg < kth), -1e30, lg)
-    # top-p (nucleus) on the top-k-masked logits (p=1 -> off)
-    sorted_lg = jnp.sort(lg, axis=-1)[:, ::-1]
-    cum = jnp.cumsum(jax.nn.softmax(sorted_lg, axis=-1), axis=-1)
-    cutoff_idx = jnp.sum(cum < top_p[:, None], axis=-1, keepdims=True)
-    cutoff = jnp.take_along_axis(sorted_lg, jnp.clip(cutoff_idx, 0, V - 1),
-                                 axis=-1)
-    lg = jnp.where((top_p[:, None] < 1.0) & (lg < cutoff), -1e30, lg)
+        lg = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        # top-k: mask strictly below each row's k-th largest logit (k=0 -> off)
+        sorted_lg = jnp.sort(lg, axis=-1)[:, ::-1]
+        kth = jnp.take_along_axis(
+            sorted_lg, jnp.clip(top_k - 1, 0, V - 1)[:, None], axis=-1)
+        lg = jnp.where((top_k[:, None] > 0) & (lg < kth), -1e30, lg)
+        # top-p (nucleus) on the top-k-masked logits (p=1 -> off)
+        sorted_lg = jnp.sort(lg, axis=-1)[:, ::-1]
+        cum = jnp.cumsum(jax.nn.softmax(sorted_lg, axis=-1), axis=-1)
+        cutoff_idx = jnp.sum(cum < top_p[:, None], axis=-1, keepdims=True)
+        cutoff = jnp.take_along_axis(sorted_lg,
+                                     jnp.clip(cutoff_idx, 0, V - 1), axis=-1)
+        lg = jnp.where((top_p[:, None] < 1.0) & (lg < cutoff), -1e30, lg)
 
-    keys = jax.vmap(_row_key)(seed, rid, pos)
-    sampled = jax.vmap(lambda k, row: jax.random.categorical(k, row))(keys, lg)
-    return jnp.where(temperature <= 0.0, greedy, sampled.astype(jnp.int32))
+        keys = jax.vmap(_row_key)(seed, rid, pos)
+        sampled = jax.vmap(lambda k, row: jax.random.categorical(k, row))(
+            keys, lg)
+        return jnp.where(temperature <= 0.0, greedy, sampled.astype(jnp.int32))

@@ -333,13 +333,15 @@ def write_kv_token(kpg, vpg, k, v, block_table, seq_lens, active=None):
     kpg/vpg [N, ps, KV_p, hd]; k/v [B, KV_p, hd]. The last page of the pool
     is the trash page for inactive slots (never allocated by the engine).
     """
-    ps = kpg.shape[1]
-    pidx = jnp.take_along_axis(block_table, (seq_lens // ps)[:, None], 1)[:, 0]
-    off = seq_lens % ps
-    if active is not None:
-        trash = kpg.shape[0] - 1
-        pidx = jnp.where(active, pidx, trash)
-    return kpg.at[pidx, off].set(k), vpg.at[pidx, off].set(v)
+    with jax.named_scope("kv_write"):
+        ps = kpg.shape[1]
+        pidx = jnp.take_along_axis(block_table, (seq_lens // ps)[:, None],
+                                   1)[:, 0]
+        off = seq_lens % ps
+        if active is not None:
+            trash = kpg.shape[0] - 1
+            pidx = jnp.where(active, pidx, trash)
+        return kpg.at[pidx, off].set(k), vpg.at[pidx, off].set(v)
 
 
 def default_paged_attn(q, kpg, vpg, block_table, kv_lens, q_positions, *,
@@ -388,7 +390,8 @@ def decode(params, cfg, tokens, k_pages, v_pages, block_table, seq_lens, *,
     moe_fn = moe_fn or (default_moe_fn(cfg) if cfg.is_moe else None)
     windows = jnp.asarray(layer_windows(cfg))
 
-    x = embed(params, cfg, tokens, policy)        # [B, D]
+    with jax.named_scope("embed"):
+        x = embed(params, cfg, tokens, policy)    # [B, D]
     if policy is not None:
         x = constrain(x, policy, "batch", None)
     B, D = x.shape
@@ -398,24 +401,28 @@ def decode(params, cfg, tokens, k_pages, v_pages, block_table, seq_lens, *,
     def body(carry, xs):
         xc, aux = carry
         lp, kpg, vpg, win = xs
-        h = rms_norm(xc, lp["ln1"], cfg.norm_eps)
-        q, k, v = _qkv(cfg, lay, lp, h)            # q [B,H_p,hd]; k/v [B,KV,hd]
-        q = rope(q[:, None], pos[:, None], cfg.rope_theta)       # [B,1,H_p,hd]
-        k = rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-        ke = _expand_kv(k, kv_map, policy, ("batch", "kv_heads", None))
-        ve = _expand_kv(v, kv_map, policy, ("batch", "kv_heads", None))
-        o, kpg, vpg = attn_fn(q, ke, ve, kpg, vpg, block_table, seq_lens, act,
-                              scale=_attn_scale(cfg), window=win,
-                              attn_softcap=cfg.attn_logit_softcap)
-        attn_out = _o_proj(cfg, lp, o[:, 0], head_mask)
-        if cfg.post_attn_norm:
-            attn_out = rms_norm(attn_out, lp["ln1b"], cfg.norm_eps)
-        xc = xc + attn_out
-        h2 = rms_norm(xc, lp["ln2"], cfg.norm_eps)
-        y, a = _ffn(cfg, lp, h2, moe_fn)
-        if cfg.post_attn_norm:
-            y = rms_norm(y, lp["ln2b"], cfg.norm_eps)
-        xc = xc + y
+        with jax.named_scope("qkv"):
+            h = rms_norm(xc, lp["ln1"], cfg.norm_eps)
+            q, k, v = _qkv(cfg, lay, lp, h)        # q [B,H_p,hd]; k/v [B,KV,hd]
+        with jax.named_scope("decode_attn"):
+            q = rope(q[:, None], pos[:, None], cfg.rope_theta)   # [B,1,H_p,hd]
+            k = rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+            ke = _expand_kv(k, kv_map, policy, ("batch", "kv_heads", None))
+            ve = _expand_kv(v, kv_map, policy, ("batch", "kv_heads", None))
+            o, kpg, vpg = attn_fn(q, ke, ve, kpg, vpg, block_table, seq_lens,
+                                  act, scale=_attn_scale(cfg), window=win,
+                                  attn_softcap=cfg.attn_logit_softcap)
+        with jax.named_scope("o_proj"):
+            attn_out = _o_proj(cfg, lp, o[:, 0], head_mask)
+            if cfg.post_attn_norm:
+                attn_out = rms_norm(attn_out, lp["ln1b"], cfg.norm_eps)
+            xc = xc + attn_out
+        with jax.named_scope("ffn"):
+            h2 = rms_norm(xc, lp["ln2"], cfg.norm_eps)
+            y, a = _ffn(cfg, lp, h2, moe_fn)
+            if cfg.post_attn_norm:
+                y = rms_norm(y, lp["ln2b"], cfg.norm_eps)
+            xc = xc + y
         if policy is not None:
             xc = constrain(xc, policy, "batch", None)
         return (xc, aux + a), (kpg, vpg)
@@ -423,8 +430,9 @@ def decode(params, cfg, tokens, k_pages, v_pages, block_table, seq_lens, *,
     (x, _), (k_pages, v_pages) = jax.lax.scan(
         body, (x, jnp.float32(0.0)),
         (params["blocks"], k_pages, v_pages, windows))
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    return unembed(params, cfg, x, policy), (k_pages, v_pages)
+    with jax.named_scope("logits"):
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        return unembed(params, cfg, x, policy), (k_pages, v_pages)
 
 
 # ------------------------------------------------------------------- mixed -
@@ -434,20 +442,21 @@ def write_kv_chunk(kpg, vpg, k, v, block_table, start, lens):
     kpg [N, ps, KV_p, hd]; k/v [P, C, KV_p, hd]; block_table [P, Pmax];
     start [P] first position of chunk; lens [P] valid tokens (rest->trash).
     """
-    P, C = k.shape[:2]
-    ps = kpg.shape[1]
-    j = jnp.arange(C, dtype=jnp.int32)[None]                   # [1, C]
-    gpos = start[:, None] + j                                  # [P, C]
-    page_slot = gpos // ps
-    pidx = jnp.take_along_axis(block_table, page_slot, axis=1) # [P, C]
-    off = gpos % ps
-    trash = kpg.shape[0] - 1
-    valid = j < lens[:, None]
-    pidx = jnp.where(valid, pidx, trash)
-    flat = lambda t: t.reshape((P * C,) + t.shape[2:])
-    kpg = kpg.at[flat(pidx), flat(off)].set(flat(k))
-    vpg = vpg.at[flat(pidx), flat(off)].set(flat(v))
-    return kpg, vpg
+    with jax.named_scope("kv_write"):
+        P, C = k.shape[:2]
+        ps = kpg.shape[1]
+        j = jnp.arange(C, dtype=jnp.int32)[None]                   # [1, C]
+        gpos = start[:, None] + j                                  # [P, C]
+        page_slot = gpos // ps
+        pidx = jnp.take_along_axis(block_table, page_slot, axis=1) # [P, C]
+        off = gpos % ps
+        trash = kpg.shape[0] - 1
+        valid = j < lens[:, None]
+        pidx = jnp.where(valid, pidx, trash)
+        flat = lambda t: t.reshape((P * C,) + t.shape[2:])
+        kpg = kpg.at[flat(pidx), flat(off)].set(flat(k))
+        vpg = vpg.at[flat(pidx), flat(off)].set(flat(v))
+        return kpg, vpg
 
 
 def mixed(params, cfg, mb, k_pages, v_pages, *, attn_fn=None, tp=1,
@@ -484,9 +493,10 @@ def mixed(params, cfg, mb, k_pages, v_pages, *, attn_fn=None, tp=1,
     B = mb["d_tokens"].shape[0]
     D = cfg.d_model
 
-    xp = embed(params, cfg, mb["p_tokens"], policy)            # [P, C, D]
-    xd = embed(params, cfg, mb["d_tokens"], policy)            # [B, D]
-    x = jnp.concatenate([xp.reshape(P * C, D), xd], axis=0)    # [P*C+B, D]
+    with jax.named_scope("embed"):
+        xp = embed(params, cfg, mb["p_tokens"], policy)        # [P, C, D]
+        xd = embed(params, cfg, mb["d_tokens"], policy)        # [B, D]
+        x = jnp.concatenate([xp.reshape(P * C, D), xd], axis=0)  # [P*C+B, D]
     if policy is not None:
         x = constrain(x, policy, "tokens", None)
 
@@ -497,42 +507,48 @@ def mixed(params, cfg, mb, k_pages, v_pages, *, attn_fn=None, tp=1,
     def body(carry, xs):
         xc, aux = carry
         lp, kpg, vpg, win = xs
-        h = rms_norm(xc, lp["ln1"], cfg.norm_eps)
-        q, k, v = _qkv(cfg, lay, lp, h)                        # the shared GEMM
+        with jax.named_scope("qkv"):
+            h = rms_norm(xc, lp["ln1"], cfg.norm_eps)
+            q, k, v = _qkv(cfg, lay, lp, h)                    # the shared GEMM
         qp, qd = q[: P * C].reshape(P, C, H_p, -1), q[P * C :][:, None]
         kp, kd = k[: P * C].reshape(P, C, cfg.n_kv_heads, -1), k[P * C :]
         vp, vd = v[: P * C].reshape(P, C, cfg.n_kv_heads, -1), v[P * C :]
 
         # --- prefill-phase attention (write chunk KV + attend history) ---
-        qp = rope(qp, p_pos, cfg.rope_theta)
-        kp = rope(kp, p_pos, cfg.rope_theta)
-        kpe = _expand_kv(kp, kv_map)
-        vpe = _expand_kv(vp, kv_map)
-        o_p, kpg, vpg = chunk_attn(qp, kpe, vpe, kpg, vpg, mb["p_table"],
-                                   mb["p_start"], mb["p_lens"],
-                                   scale=_attn_scale(cfg), window=win,
-                                   attn_softcap=cfg.attn_logit_softcap)
+        with jax.named_scope("chunk_attn"):
+            qp = rope(qp, p_pos, cfg.rope_theta)
+            kp = rope(kp, p_pos, cfg.rope_theta)
+            kpe = _expand_kv(kp, kv_map)
+            vpe = _expand_kv(vp, kv_map)
+            o_p, kpg, vpg = chunk_attn(qp, kpe, vpe, kpg, vpg, mb["p_table"],
+                                       mb["p_start"], mb["p_lens"],
+                                       scale=_attn_scale(cfg), window=win,
+                                       attn_softcap=cfg.attn_logit_softcap)
 
         # --- decode-phase attention ---
-        qd = rope(qd, d_pos[:, None], cfg.rope_theta)
-        kd = rope(kd[:, None], d_pos[:, None], cfg.rope_theta)[:, 0]
-        kde = _expand_kv(kd, kv_map)
-        vde = _expand_kv(vd, kv_map)
-        o_d, kpg, vpg = decode_attn(qd, kde, vde, kpg, vpg, mb["d_table"],
-                                    mb["d_lens"], mb["d_active"],
-                                    scale=_attn_scale(cfg), window=win,
-                                    attn_softcap=cfg.attn_logit_softcap)
+        with jax.named_scope("decode_attn"):
+            qd = rope(qd, d_pos[:, None], cfg.rope_theta)
+            kd = rope(kd[:, None], d_pos[:, None], cfg.rope_theta)[:, 0]
+            kde = _expand_kv(kd, kv_map)
+            vde = _expand_kv(vd, kv_map)
+            o_d, kpg, vpg = decode_attn(qd, kde, vde, kpg, vpg, mb["d_table"],
+                                        mb["d_lens"], mb["d_active"],
+                                        scale=_attn_scale(cfg), window=win,
+                                        attn_softcap=cfg.attn_logit_softcap)
 
-        o = jnp.concatenate([o_p.reshape(P * C, H_p, -1), o_d[:, 0]], axis=0)
-        attn_out = _o_proj(cfg, lp, o, head_mask)              # shared GEMM
-        if cfg.post_attn_norm:
-            attn_out = rms_norm(attn_out, lp["ln1b"], cfg.norm_eps)
-        xc = xc + attn_out
-        h2 = rms_norm(xc, lp["ln2"], cfg.norm_eps)
-        y, a = _ffn(cfg, lp, h2, moe_fn)                       # shared GEMM
-        if cfg.post_attn_norm:
-            y = rms_norm(y, lp["ln2b"], cfg.norm_eps)
-        xc = xc + y
+        with jax.named_scope("o_proj"):
+            o = jnp.concatenate([o_p.reshape(P * C, H_p, -1), o_d[:, 0]],
+                                axis=0)
+            attn_out = _o_proj(cfg, lp, o, head_mask)          # shared GEMM
+            if cfg.post_attn_norm:
+                attn_out = rms_norm(attn_out, lp["ln1b"], cfg.norm_eps)
+            xc = xc + attn_out
+        with jax.named_scope("ffn"):
+            h2 = rms_norm(xc, lp["ln2"], cfg.norm_eps)
+            y, a = _ffn(cfg, lp, h2, moe_fn)                   # shared GEMM
+            if cfg.post_attn_norm:
+                y = rms_norm(y, lp["ln2b"], cfg.norm_eps)
+            xc = xc + y
         if policy is not None:
             xc = constrain(xc, policy, "tokens", None)
         return (xc, aux + a), (kpg, vpg)
@@ -540,13 +556,13 @@ def mixed(params, cfg, mb, k_pages, v_pages, *, attn_fn=None, tp=1,
     (x, aux), (k_pages, v_pages) = jax.lax.scan(
         body, (x, jnp.float32(0.0)),
         (params["blocks"], k_pages, v_pages, windows))
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-
-    xp = x[: P * C].reshape(P, C, D)
-    last_idx = jnp.clip(mb["p_lens"] - 1, 0, C - 1)
-    xp_last = xp[jnp.arange(P), last_idx]                      # [P, D]
-    p_logits = unembed(params, cfg, xp_last, policy)
-    d_logits = unembed(params, cfg, x[P * C :], policy)
+    with jax.named_scope("logits"):
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        xp = x[: P * C].reshape(P, C, D)
+        last_idx = jnp.clip(mb["p_lens"] - 1, 0, C - 1)
+        xp_last = xp[jnp.arange(P), last_idx]                  # [P, D]
+        p_logits = unembed(params, cfg, xp_last, policy)
+        d_logits = unembed(params, cfg, x[P * C :], policy)
     return p_logits, d_logits, (k_pages, v_pages), aux
 
 
