@@ -136,14 +136,17 @@ def build_step_programs(cfg, kv_dtype: str) -> StepPrograms:
                   if int8 else None)
 
     def commit(kpg, vpg, k_new, v_new, dest):
-        # k_new [L, M, ps, KV_p, hd]; dest [M] page ids (trash for pads)
+        # k_new [L, M, ps, KV_p * hd] (``kv_to_pages``); dest [M] page ids
+        # (trash for pads)
         if int8:
-            kq, vq = quant_kv(k_new, v_new)
-            return ({"q": kpg["q"].at[:, dest].set(kq["q"]),
-                     "s": kpg["s"].at[:, dest].set(kq["s"])},
-                    {"q": vpg["q"].at[:, dest].set(vq["q"]),
-                     "s": vpg["s"].at[:, dest].set(vq["s"])})
-        return kpg.at[:, dest].set(k_new), vpg.at[:, dest].set(v_new)
+            heads = lambda t: t.reshape(*t.shape[:3], t.shape[3] // cfg.head_dim,
+                                        cfg.head_dim)
+            k_new, v_new = quant_kv(heads(k_new), heads(v_new))
+
+        def put(pool, new):
+            return pool.at[:, dest].set(new.reshape(*new.shape[:3],
+                                                    pool.shape[-1]))
+        return jax.tree.map(put, kpg, k_new), jax.tree.map(put, vpg, v_new)
 
     def decode_fn(params, tokens, kpg, vpg, bt, lens, active):
         return T.decode(params, cfg, tokens, kpg, vpg, bt, lens,
@@ -664,7 +667,7 @@ class Engine:
         dst = jnp.asarray([d for _, d in pairs], jnp.int32)
 
         def copy(a):
-            # tree-mapped: fp pools are bare [L, N, ps, KV_p, d] arrays,
+            # tree-mapped: fp pools are bare [L, N, ps, KV_p * d] arrays,
             # int8 pools are {"q": codes, "s": scales} dicts — a COW copy
             # must move the scale sidecar WITH the codes
             return a.at[:, dst].set(a[:, src])

@@ -1,9 +1,10 @@
 """Host-side paged KV-cache bookkeeping.
 
-The device-side page pool is a plain array [L, N, ps, KV_p, hd] owned by
-the engine; this module owns the allocator + per-request block tables —
-the paper's "mapping between the inference request ... and the generated
-KV-cache file" (§II-G), solved with block tables instead of files.
+The device-side page pool is a plain array [L, N, ps, KV_p * hd]
+(``transformer.init_pages``) owned by the engine; this module owns the
+allocator + per-request block tables — the paper's "mapping between the
+inference request ... and the generated KV-cache file" (§II-G), solved
+with block tables instead of files.
 
 Pages are **refcounted**: with a :class:`~repro.core.prefix_cache.PrefixCache`
 attached, byte-identical prefixes across requests map to the *same*

@@ -16,10 +16,14 @@ This module is the single entry point for the int8 path:
     now re-exports them.
   * :func:`int8_decode_attn` / :func:`int8_chunk_attn` — drop-in
     ``default_decode_attn`` / ``default_chunk_attn`` replacements over
-    page *dicts* ``{"q": int8 codes [.., hd], "s": f32 scales [.., 1]}``.
-    ``jax.lax.scan`` carries dict pytrees through ``transformer.decode`` /
-    ``transformer.mixed`` unchanged, so the engine flips paths by swapping
-    ``attn_fn`` and the page pytree only.
+    page *dicts* ``{"q": int8 codes [L, N, ps, KV_p * hd], "s": f32
+    scales [L, N, ps, KV_p]}``.  Same contract as the fp functions: they take
+    the whole dict pools and the scan's layer index, scatter the new
+    codes and scales at ``[layer, page, offset]`` (one scatter per leaf),
+    gather that layer's pages by ``(layer, block_table)``, and return the
+    whole dict pools.  ``transformer.decode`` / ``transformer.mixed``
+    carry the dict pools through their layer scans like fp pools, so the
+    engine flips paths by swapping ``attn_fn`` and the page pytree only.
   * the Pallas dequant-in-kernel variant lives in
     ``kernels/paged_attention_int8.py`` (TPU tiling; validated in
     interpret mode against :func:`paged_attention_int8` here).
@@ -62,26 +66,29 @@ def q8_kv(t):
 
 
 def paged_attention_int8(q, kpg, kps, vpg, vps, block_table, kv_lens,
-                         q_positions, *, scale, window, attn_softcap):
+                         q_positions, *, scale, window, attn_softcap,
+                         layer=None):
     """paged attention over int8 pages (codes kpg/vpg + scales kps/vps).
 
     jnp reference path: gathers the sequence's pages and runs the flash
     scan with ``k_scale``/``v_scale``, so dequant happens inside the
     blockwise loop (codes travel through HBM, floats never materialize
-    per-page).  The Pallas TPU variant mirrors this exactly.
+    per-page).  The Pallas TPU variant mirrors this exactly.  Pages are
+    whole pools (``init_pages_int8``) read at ``layer``, or with ``layer``
+    None one layer in the Pallas kernel's layout (codes [N, ps, KV_p, hd],
+    scales [N, ps, KV_p, 1]), for its oracle.
     """
-    B, Pmax = block_table.shape
-    ps = kpg.shape[1]
-    k = gather_pages(kpg, block_table)
-    v = gather_pages(vpg, block_table)
-    ks = gather_pages(kps, block_table)
-    vs = gather_pages(vps, block_table)
-    kv_pos = jnp.broadcast_to(
-        jnp.arange(Pmax * ps, dtype=jnp.int32)[None], (B, Pmax * ps))
+    d = q.shape[-1]
+    k = gather_pages(kpg, block_table, layer, d)
+    v = gather_pages(vpg, block_table, layer, d)
+    ks = gather_pages(kps, block_table, layer, 1)
+    vs = gather_pages(vps, block_table, layer, 1)
+    B, Tk = k.shape[:2]
+    kv_pos = jnp.broadcast_to(jnp.arange(Tk, dtype=jnp.int32)[None], (B, Tk))
     return flash_attention(
         q, k, v, q_positions=q_positions, kv_positions=kv_pos,
         kv_valid_len=kv_lens, scale=scale, causal=True, window=window,
-        attn_softcap=attn_softcap, block_kv=min(512, Pmax * ps),
+        attn_softcap=attn_softcap, block_kv=min(512, Tk),
         k_scale=ks, v_scale=vs)
 
 
@@ -110,37 +117,39 @@ def fake_quant_kv(t):
     return (q.astype(jnp.float32) * s).astype(t.dtype)
 
 
-def int8_decode_attn(q, k_new, v_new, kpg, vpg, block_table, seq_lens,
+def int8_decode_attn(q, k_new, v_new, kpg, vpg, layer, block_table, seq_lens,
                      active, *, scale, window, attn_softcap):
     """``default_decode_attn`` over int8 page dicts.
 
-    q [B,1,H_p,hd]; k_new/v_new [B,KV_p,hd] fp; kpg/vpg ``{"q", "s"}``.
-    Quantizes the new token at write, attends with in-scan dequant.
+    q [B,1,H_p,hd]; k_new/v_new [B,KV_p,hd] fp; kpg/vpg ``{"q", "s"}``
+    whole pools.  Quantizes the new token at write, attends with in-scan
+    dequant.
     """
     kn, vn = quant_kv(k_new, v_new)
-    kc, vc = write_kv_token(kpg["q"], vpg["q"], kn["q"], vn["q"],
+    kc, vc = write_kv_token(kpg["q"], vpg["q"], layer, kn["q"], vn["q"],
                             block_table, seq_lens, active)
-    ksc, vsc = write_kv_token(kpg["s"], vpg["s"], kn["s"], vn["s"],
+    ksc, vsc = write_kv_token(kpg["s"], vpg["s"], layer, kn["s"], vn["s"],
                               block_table, seq_lens, active)
     kpg = {"q": kc, "s": ksc}
     vpg = {"q": vc, "s": vsc}
     o = paged_attention_int8(q, kpg["q"], kpg["s"], vpg["q"], vpg["s"],
                              block_table, seq_lens + 1, seq_lens[:, None],
                              scale=scale, window=window,
-                             attn_softcap=attn_softcap)
+                             attn_softcap=attn_softcap, layer=layer)
     return o, kpg, vpg
 
 
-def int8_chunk_attn(q, k_new, v_new, kpg, vpg, block_table, start, lens, *,
-                    scale, window, attn_softcap):
+def int8_chunk_attn(q, k_new, v_new, kpg, vpg, layer, block_table, start,
+                    lens, *, scale, window, attn_softcap):
     """``default_chunk_attn`` over int8 page dicts.
 
-    q [P,C,H_p,hd]; k_new/v_new [P,C,KV_p,hd] fp; kpg/vpg ``{"q", "s"}``.
+    q [P,C,H_p,hd]; k_new/v_new [P,C,KV_p,hd] fp; kpg/vpg ``{"q", "s"}``
+    whole pools.
     """
     kn, vn = quant_kv(k_new, v_new)
-    kc, vc = write_kv_chunk(kpg["q"], vpg["q"], kn["q"], vn["q"],
+    kc, vc = write_kv_chunk(kpg["q"], vpg["q"], layer, kn["q"], vn["q"],
                             block_table, start, lens)
-    ksc, vsc = write_kv_chunk(kpg["s"], vpg["s"], kn["s"], vn["s"],
+    ksc, vsc = write_kv_chunk(kpg["s"], vpg["s"], layer, kn["s"], vn["s"],
                               block_table, start, lens)
     kpg = {"q": kc, "s": ksc}
     vpg = {"q": vc, "s": vsc}
@@ -149,25 +158,26 @@ def int8_chunk_attn(q, k_new, v_new, kpg, vpg, block_table, start, lens, *,
     o = paged_attention_int8(q, kpg["q"], kpg["s"], vpg["q"], vpg["s"],
                              block_table, start + lens, q_pos,
                              scale=scale, window=window,
-                             attn_softcap=attn_softcap)
+                             attn_softcap=attn_softcap, layer=layer)
     return o, kpg, vpg
 
 
 def init_pages_int8(cfg, n_pages, page_size, tp=1, n_layers=None):
     """int8 page pools: ({"q", "s"}, {"q", "s"}) zero-initialized.
 
-    Codes [L, N, ps, KV_p, hd] int8; scales [L, N, ps, KV_p, 1] f32
-    (floored — a zero-filled scale plane would make the all-zero pool
-    rows un-representable, see :data:`SCALE_FLOOR`).
+    Codes [L, N, ps, KV_p * hd] int8; scales [L, N, ps, KV_p] f32: a
+    token's heads in one row, as in ``transformer.init_pages``.  Scales
+    are floored — a zero-filled scale plane would make the all-zero pool
+    rows un-representable, see :data:`SCALE_FLOOR`.
     """
     from repro.models.transformer import gqa_layout
     _, KV_p, _, _, _ = gqa_layout(cfg.n_heads, cfg.n_kv_heads, tp)
     L = n_layers if n_layers is not None else cfg.n_layers
-    shape = (L, n_pages, page_size, KV_p)
-    k = {"q": jnp.zeros(shape + (cfg.head_dim,), jnp.int8),
-         "s": jnp.full(shape + (1,), SCALE_FLOOR, jnp.float32)}
-    v = {"q": jnp.zeros(shape + (cfg.head_dim,), jnp.int8),
-         "s": jnp.full(shape + (1,), SCALE_FLOOR, jnp.float32)}
+    shape = (L, n_pages, page_size)
+    k = {"q": jnp.zeros(shape + (KV_p * cfg.head_dim,), jnp.int8),
+         "s": jnp.full(shape + (KV_p,), SCALE_FLOOR, jnp.float32)}
+    v = {"q": jnp.zeros(shape + (KV_p * cfg.head_dim,), jnp.int8),
+         "s": jnp.full(shape + (KV_p,), SCALE_FLOOR, jnp.float32)}
     return k, v
 
 

@@ -46,6 +46,9 @@ def make_sharded_decode_attn(mesh, *, data=("data",), model="model",
                              shard_batch=True, kv_int8=False):
     """default_decode_attn-shaped write+attend step inside shard_map.
 
+    The whole pools [L, N, ps, KV_p * hd] come in with an unsharded layer
+    axis, the row sharded over model (whole heads per shard), and the
+    layer index as a replicated operand.
     shard_batch=False replicates the (tiny) decode batch over data —
     pages then shard over their PAGE dim instead (single-sequence long-
     context layout)."""
@@ -53,40 +56,42 @@ def make_sharded_decode_attn(mesh, *, data=("data",), model="model",
     if shard_batch:
         q_spec = P(d, None, model, None)
         kn_spec = P(d, model, None)
-        pg_spec = P(d, None, model, None)
+        pg_spec = P(None, d, None, model)
         bt_spec, v_spec = P(d, None), P(d)
     else:
         q_spec = P(None, None, model, None)
         kn_spec = P(None, model, None)
-        pg_spec = P(None, None, model, None)      # replicated over data
+        pg_spec = P(None, None, None, model)      # replicated over data
         bt_spec, v_spec = P(None, None), P(None)
 
-    def local(q, k_new, v_new, kpg, vpg, bt, lens, active, win, *, scale,
-              softcap):
+    def local(q, k_new, v_new, kpg, vpg, layer, bt, lens, active, win, *,
+              scale, softcap):
         if kv_int8:
-            bt_loc = bt % kpg["q"].shape[0]
-            return int8_decode_attn(q, k_new, v_new, kpg, vpg, bt_loc, lens,
-                                    active, scale=scale, window=win,
+            bt_loc = bt % kpg["q"].shape[1]
+            return int8_decode_attn(q, k_new, v_new, kpg, vpg, layer, bt_loc,
+                                    lens, active, scale=scale, window=win,
                                     attn_softcap=softcap)
-        bt_loc = bt % kpg.shape[0]
-        kpg, vpg = write_kv_token(kpg, vpg, k_new, v_new, bt_loc, lens, active)
+        bt_loc = bt % kpg.shape[1]
+        kpg, vpg = write_kv_token(kpg, vpg, layer, k_new, v_new, bt_loc, lens,
+                                  active)
         o = paged_attention_ref(q, kpg, vpg, bt_loc, lens + 1, lens[:, None],
-                                scale=scale, window=win, attn_softcap=softcap)
+                                scale=scale, layer=layer, window=win,
+                                attn_softcap=softcap)
         return o, kpg, vpg
 
-    def fn(q, k_new, v_new, kpg, vpg, bt, lens, active, *, scale, window,
-           attn_softcap):
+    def fn(q, k_new, v_new, kpg, vpg, layer, bt, lens, active, *, scale,
+           window, attn_softcap):
         win = jnp.asarray(window if window is not None else 2**30, jnp.int32)
         body = functools.partial(local, scale=scale, softcap=attn_softcap)
         pspec = {"q": pg_spec, "s": pg_spec} if kv_int8 else pg_spec
         mapped = shard_map(
             body, mesh=mesh,
-            in_specs=(q_spec, kn_spec, kn_spec, pspec, pspec, bt_spec,
+            in_specs=(q_spec, kn_spec, kn_spec, pspec, pspec, P(), bt_spec,
                       v_spec, v_spec, P()),
             out_specs=(q_spec, pspec, pspec),
             check_vma=False,
         )
-        return mapped(q, k_new, v_new, kpg, vpg, bt, lens, active, win)
+        return mapped(q, k_new, v_new, kpg, vpg, layer, bt, lens, active, win)
 
     return fn
 
@@ -94,42 +99,45 @@ def make_sharded_decode_attn(mesh, *, data=("data",), model="model",
 def make_sharded_chunk_attn(mesh, *, data=("data",), model="model",
                             kv_int8=False):
     """default_chunk_attn-shaped step: chunked-prefill write + attend over
-    paged history. Streams shard over data (engine pins stream i to data
-    shard i*P/n_data)."""
+    paged history, on the whole pools with the layer index replicated.
+    Streams shard over data (engine pins stream i to data shard
+    i*P/n_data)."""
     d = _dspec(data)
     q_spec = P(d, None, model, None)
     kn_spec = P(d, None, model, None)
-    pg_spec = P(d, None, model, None)
+    pg_spec = P(None, d, None, model)
     bt_spec, v_spec = P(d, None), P(d)
 
-    def local(q, k_new, v_new, kpg, vpg, bt, start, lens, win, *, scale,
-              softcap):
+    def local(q, k_new, v_new, kpg, vpg, layer, bt, start, lens, win, *,
+              scale, softcap):
         C = q.shape[1]
         q_pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         if kv_int8:
-            bt_loc = bt % kpg["q"].shape[0]
-            return int8_chunk_attn(q, k_new, v_new, kpg, vpg, bt_loc, start,
-                                   lens, scale=scale, window=win,
+            bt_loc = bt % kpg["q"].shape[1]
+            return int8_chunk_attn(q, k_new, v_new, kpg, vpg, layer, bt_loc,
+                                   start, lens, scale=scale, window=win,
                                    attn_softcap=softcap)
-        bt_loc = bt % kpg.shape[0]
-        kpg, vpg = write_kv_chunk(kpg, vpg, k_new, v_new, bt_loc, start, lens)
+        bt_loc = bt % kpg.shape[1]
+        kpg, vpg = write_kv_chunk(kpg, vpg, layer, k_new, v_new, bt_loc,
+                                  start, lens)
         o = paged_attention_ref(q, kpg, vpg, bt_loc, start + lens, q_pos,
-                                scale=scale, window=win, attn_softcap=softcap)
+                                scale=scale, layer=layer, window=win,
+                                attn_softcap=softcap)
         return o, kpg, vpg
 
-    def fn(q, k_new, v_new, kpg, vpg, bt, start, lens, *, scale, window,
-           attn_softcap):
+    def fn(q, k_new, v_new, kpg, vpg, layer, bt, start, lens, *, scale,
+           window, attn_softcap):
         win = jnp.asarray(window if window is not None else 2**30, jnp.int32)
         body = functools.partial(local, scale=scale, softcap=attn_softcap)
         pspec = {"q": pg_spec, "s": pg_spec} if kv_int8 else pg_spec
         mapped = shard_map(
             body, mesh=mesh,
-            in_specs=(q_spec, kn_spec, kn_spec, pspec, pspec, bt_spec,
+            in_specs=(q_spec, kn_spec, kn_spec, pspec, pspec, P(), bt_spec,
                       v_spec, v_spec, P()),
             out_specs=(q_spec, pspec, pspec),
             check_vma=False,
         )
-        return mapped(q, k_new, v_new, kpg, vpg, bt, start, lens, win)
+        return mapped(q, k_new, v_new, kpg, vpg, layer, bt, start, lens, win)
 
     return fn
 

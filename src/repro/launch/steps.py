@@ -21,6 +21,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import TrainConfig, get_config
+from repro.kernels.kv_int8 import init_pages_int8
 from repro.launch import spmd
 from repro.launch.shardings import param_pspecs
 from repro.models import encdec, hybrid, rwkv
@@ -242,17 +243,20 @@ def build_prefill(arch, mesh, shape_name="prefill_32k"):
 
 
 # ----------------------------------------------------------------- decode --
-def _page_pool_shapes(cfg, tp, n_seqs, seq, n_data, n_layers=None,
-                      extra_seqs=0):
-    """(pages shape [L,N,ps,KV_p,hd], Pmax). N is data-divisible and
-    includes per-shard trash pages."""
-    _, KV_p, _, _, _ = T.gqa_layout(cfg.n_heads, cfg.n_kv_heads, tp)
-    L = n_layers if n_layers is not None else cfg.n_layers
+def _page_pools(cfg, tp, n_seqs, seq, n_data, n_layers=None, extra_seqs=0,
+                kv_int8=False):
+    """(abstract bf16 or int8 k/v page pools, as the engine lays them out,
+    Pmax). N is data-divisible and includes per-shard trash pages."""
     per_seq = seq // PAGE + 1
     n_raw = (n_seqs + extra_seqs) * per_seq + n_data
     N = -(-n_raw // n_data) * n_data
-    Pmax = per_seq
-    return (L, N, PAGE, KV_p, cfg.head_dim), Pmax
+    if kv_int8:
+        pools = jax.eval_shape(lambda: init_pages_int8(
+            cfg, N, PAGE, tp=tp, n_layers=n_layers))
+    else:
+        pools = jax.eval_shape(lambda: T.init_pages(
+            cfg, N, PAGE, tp=tp, dtype=BF16, n_layers=n_layers))
+    return pools, per_seq
 
 
 def build_decode(arch, mesh, shape_name="decode_32k", scheme="zero3"):
@@ -323,8 +327,8 @@ def build_decode(arch, mesh, shape_name="decode_32k", scheme="zero3"):
 
     if cfg.family == "hybrid":
         n_attn, n_mamba, _, _, _ = hybrid.group_structure(cfg)
-        pg_shape, Pmax = _page_pool_shapes(cfg, tp, B, S, n_data,
-                                           n_layers=n_attn)
+        (kpg, vpg), Pmax = _page_pools(cfg, tp, B, S, n_data,
+                                       n_layers=n_attn)
         conv_sh, ssm_sh = None, None
         cs, ss = __import__("repro.models.ssm", fromlist=["x"]).mamba2_state_shapes(cfg, B)
         conv_shapes = {k: sds((n_mamba,) + v, BF16) for k, v in cs.items()}
@@ -334,16 +338,15 @@ def build_decode(arch, mesh, shape_name="decode_32k", scheme="zero3"):
                       "B": P(None, db, None, None),
                       "C": P(None, db, None, None)}
         ssm_specs = P(None, db, "model", None, None)
-        pg_spec = (P(None, d, None, "model", None) if shard_batch
-                   else P(None, None, None, "model", None))
+        pg_spec = (P(None, d, None, "model") if shard_batch
+                   else P(None, None, None, "model"))
         attn = spmd.make_sharded_decode_attn(mesh, data=da, model="model",
                                              shard_batch=shard_batch)
         def fn(params, tokens, conv, ssm_st, kpg, vpg, bt, lens):
             return hybrid.decode(params, cfg, tokens, conv, ssm_st, kpg, vpg,
                                  bt, lens, attn_fn=attn, tp=tp, policy=policy)
         args = (params_shapes, sds((B,), I32), conv_shapes, ssm_shapes,
-                sds(pg_shape, BF16), sds(pg_shape, BF16),
-                sds((B, Pmax), I32), sds((B,), I32))
+                kpg, vpg, sds((B, Pmax), I32), sds((B,), I32))
         in_sh = (p_specs, bspec, conv_specs, ssm_specs, pg_spec, pg_spec,
                  bspec2, bspec)
         return dict(fn=fn, args=args, in_shardings=in_sh, donate=(2, 3, 4, 5),
@@ -351,17 +354,17 @@ def build_decode(arch, mesh, shape_name="decode_32k", scheme="zero3"):
                     note=f"decode(hybrid) B={B} ctx={S} attn_layers={n_attn}")
 
     if cfg.family == "encdec":
-        pg_shape, Pmax = _page_pool_shapes(cfg, tp, B, S, n_data)
+        (kpg, vpg), Pmax = _page_pools(cfg, tp, B, S, n_data)
         _, KV_p, _, _, _ = T.gqa_layout(cfg.n_heads, cfg.n_kv_heads, tp)
         cross_shape = (cfg.n_layers, B, cfg.encoder_seq, KV_p, cfg.head_dim)
-        pg_spec = P(None, d, None, "model", None)
+        pg_spec = P(None, d, None, "model")
         cross_spec = P(None, d, None, "model", None)
         attn = spmd.make_sharded_decode_attn(mesh, data=da, model="model")
         def fn(params, tokens, kpg, vpg, xk, xv, bt, lens):
             return encdec.decode(params, cfg, tokens, kpg, vpg, xk, xv, bt,
                                  lens, attn_fn=attn, tp=tp, policy=policy)
-        args = (params_shapes, sds((B,), I32), sds(pg_shape, BF16),
-                sds(pg_shape, BF16), sds(cross_shape, BF16),
+        args = (params_shapes, sds((B,), I32), kpg, vpg,
+                sds(cross_shape, BF16),
                 sds(cross_shape, BF16), sds((B, Pmax), I32), sds((B,), I32))
         in_sh = (p_specs, bspec, pg_spec, pg_spec, cross_spec, cross_spec,
                  bspec2, bspec)
@@ -369,23 +372,23 @@ def build_decode(arch, mesh, shape_name="decode_32k", scheme="zero3"):
                     note=f"decode(encdec) B={B} ctx={S}")
 
     # transformer family
-    pg_shape, Pmax = _page_pool_shapes(cfg, tp, B, S, n_data)
+    (kpg, vpg), Pmax = _page_pools(cfg, tp, B, S, n_data)
     pages_data_sharded = shard_batch or scheme == "2d"
-    pg_spec = (P(None, d, None, "model", None) if pages_data_sharded
-               else P(None, None, None, "model", None))
+    pg_spec = (P(None, d, None, "model") if pages_data_sharded
+               else P(None, None, None, "model"))
     attn = spmd.make_sharded_decode_attn(
         mesh, data=da, model="model", shard_batch=pages_data_sharded)
     def fn(params, tokens, kpg, vpg, bt, lens):
         return T.decode(params, cfg, tokens, kpg, vpg, bt, lens,
                         attn_fn=attn, tp=tp, policy=policy, moe_fn=moe_fn)
-    args = (params_shapes, sds((B,), I32), sds(pg_shape, BF16),
-            sds(pg_shape, BF16), sds((B, Pmax), I32), sds((B,), I32))
+    args = (params_shapes, sds((B,), I32), kpg, vpg, sds((B, Pmax), I32),
+            sds((B,), I32))
     in_sh = (p_specs, bspec, pg_spec, pg_spec, bspec2, bspec)
     # 2d scheme: GEMMs are replicated over data (outer_mult), islands exact
     return dict(fn=fn, args=args, in_shardings=in_sh, donate=(2, 3),
                 flop_divisor=None if (shard_batch or flat_f) else tp,
                 outer_mult=n_data if flat_f else 1,
-                note=f"decode B={B} ctx={S} pool={pg_shape} scheme={scheme}")
+                note=f"decode B={B} ctx={S} pool={kpg.shape} scheme={scheme}")
 
 
 # ------------------------------------------------------------------ mixed --
@@ -410,10 +413,11 @@ def build_mixed(arch, mesh, shape_name="mixed_32k", scheme="baseline"):
     p_specs = param_pspecs(params_shapes, cfg, tp=tp,
                            fsdp_size=mesh.shape["data"],
                            fsdp="data" if arch in SERVE_FSDP else None)
-    pg_shape, Pmax = _page_pool_shapes(cfg, tp, B, S, n_data,
-                                       extra_seqs=Pstr)
-    pg_spec = P(None, d, None, "model", None)
     kv8 = scheme == "kv8"
+    (kpg, vpg), Pmax = _page_pools(cfg, tp, B, S, n_data, extra_seqs=Pstr,
+                                   kv_int8=kv8)
+    pg_spec = P(None, d, None, "model")
+    pg_sp = {"q": pg_spec, "s": pg_spec} if kv8 else pg_spec
     attn = {
         "decode": spmd.make_sharded_decode_attn(mesh, data=da, model="model",
                                                 kv_int8=kv8),
@@ -435,16 +439,8 @@ def build_mixed(arch, mesh, shape_name="mixed_32k", scheme="baseline"):
         p_tokens=P(d, None), p_table=P(d, None), p_start=P(d), p_lens=P(d),
         d_tokens=P(d), d_table=P(d, None), d_lens=P(d), d_active=P(d),
     )
-    if kv8:
-        sc_shape = pg_shape[:-1] + (1,)
-        pg_arg = {"q": sds(pg_shape, jnp.int8), "s": sds(sc_shape, F32)}
-        pg_sp = {"q": pg_spec, "s": pg_spec}
-        args = (params_shapes, mb_shapes, pg_arg, dict(pg_arg))
-        in_sh = (p_specs, mb_specs, pg_sp, pg_sp)
-    else:
-        args = (params_shapes, mb_shapes, sds(pg_shape, BF16),
-                sds(pg_shape, BF16))
-        in_sh = (p_specs, mb_specs, pg_spec, pg_spec)
+    args = (params_shapes, mb_shapes, kpg, vpg)
+    in_sh = (p_specs, mb_specs, pg_sp, pg_sp)
     return dict(fn=fn, args=args, in_shardings=in_sh, donate=(2, 3),
                 note=f"mixed(Splitwiser) streams={Pstr}x{C} + decode B={B} "
                      f"@ctx={S} scheme={scheme}")

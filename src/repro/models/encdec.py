@@ -213,7 +213,8 @@ def decode(params, cfg, tokens, k_pages, v_pages, cross_k, cross_v,
            policy=None, enc_valid_len=None):
     """One decoder token step.
 
-    tokens [B]; pages [Ld, N, ps, KV_p, hd]; cross_k/v [Ld, B, S_enc, KV_p, hd].
+    tokens [B]; pages [Ld, N, ps, KV_p * hd];
+    cross_k/v [Ld, B, S_enc, KV_p, hd].
     Returns (logits, (k_pages, v_pages)).
     """
     lay = gqa_layout(cfg.n_heads, cfg.n_kv_heads, tp)
@@ -228,8 +229,9 @@ def decode(params, cfg, tokens, k_pages, v_pages, cross_k, cross_v,
     S = cross_k.shape[2]
     enc_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
 
-    def body(xc, xs):
-        lp, kpg, vpg, xk, xv = xs
+    def body(carry, xs):
+        xc, kpg, vpg = carry
+        lp, xk, xv, layer = xs
         h = rms_norm(xc, lp["ln1"], cfg.norm_eps)
         q = jnp.einsum("bd,dhk->bhk", h, lp["self"]["wq"])
         k = jnp.einsum("bd,dhk->bhk", h, lp["self"]["wk"])
@@ -238,7 +240,8 @@ def decode(params, cfg, tokens, k_pages, v_pages, cross_k, cross_v,
         k = rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
         ke = jnp.take(k, jnp.asarray(kv_map), axis=-2)
         ve = jnp.take(v, jnp.asarray(kv_map), axis=-2)
-        o, kpg, vpg = attn_fn(q, ke, ve, kpg, vpg, block_table, seq_lens, act,
+        o, kpg, vpg = attn_fn(q, ke, ve, kpg, vpg, layer, block_table,
+                              seq_lens, act,
                               scale=1.0 / math.sqrt(cfg.head_dim), window=None,
                               attn_softcap=None)
         o = o[:, 0] * jnp.asarray(head_mask, o.dtype)[:, None]
@@ -254,9 +257,11 @@ def decode(params, cfg, tokens, k_pages, v_pages, cross_k, cross_v,
         xc = xc + mlp_apply(lp["mlp"], h2, cfg.mlp_act)
         if policy is not None:
             xc = constrain(xc, policy, "batch", None)
-        return xc, (kpg, vpg)
+        return (xc, kpg, vpg), None
 
-    x, (k_pages, v_pages) = jax.lax.scan(
-        body, x, (params["dec_blocks"], k_pages, v_pages, cross_k, cross_v))
+    (x, k_pages, v_pages), _ = jax.lax.scan(
+        body, (x, k_pages, v_pages),
+        (params["dec_blocks"], cross_k, cross_v,
+         jnp.arange(cross_k.shape[0], dtype=jnp.int32)))
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     return unembed(params, cfg, x, policy), (k_pages, v_pages)

@@ -188,7 +188,7 @@ def prefill(params, cfg, tokens, *, tp=1, policy=None):
 def decode(params, cfg, tokens, conv_states, ssm_states, k_pages, v_pages,
            block_table, seq_lens, *, active=None, attn_fn=None, tp=1,
            policy=None):
-    """One token step. tokens [B]; pages [n_attn, N, ps, KV_p, hd].
+    """One token step. tokens [B]; pages [n_attn, N, ps, KV_p * hd].
 
     Returns (logits, (conv, ssm), (k_pages, v_pages)).
     """
@@ -215,8 +215,9 @@ def decode(params, cfg, tokens, conv_states, ssm_states, k_pages, v_pages,
             return xc, (c2, s2)
         return jax.lax.scan(mbody, x, (mp, c0, s0))
 
-    def group_body(xc, xs):
-        mp, c0, s0, lora_i, kpg, vpg = xs
+    def group_body(carry, xs):
+        xc, kpg, vpg = carry
+        mp, c0, s0, lora_i, layer = xs
         xc, (c2, s2) = mamba_scan(xc, mp, c0, s0)
         h2 = rms_norm(jnp.concatenate([xc, x0], axis=-1),
                       params["shared"]["ln1"], cfg.norm_eps)
@@ -225,7 +226,8 @@ def decode(params, cfg, tokens, conv_states, ssm_states, k_pages, v_pages,
         k = rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
         ke = jnp.take(k, jnp.asarray(kv_map), axis=-2)
         ve = jnp.take(v, jnp.asarray(kv_map), axis=-2)
-        o, kpg, vpg = attn_fn(q, ke, ve, kpg, vpg, block_table, seq_lens, act,
+        o, kpg, vpg = attn_fn(q, ke, ve, kpg, vpg, layer, block_table,
+                              seq_lens, act,
                               scale=1.0 / math.sqrt(cfg.head_dim), window=None,
                               attn_softcap=None)
         o = o[:, 0] * jnp.asarray(head_mask, o.dtype)[:, None]
@@ -236,10 +238,11 @@ def decode(params, cfg, tokens, conv_states, ssm_states, k_pages, v_pages,
         xc = xc + y
         if policy is not None:
             xc = constrain(xc, policy, "batch", None)
-        return xc, (c2, s2, kpg, vpg)
+        return (xc, kpg, vpg), (c2, s2)
 
-    x, (cg2, sg2, k_pages, v_pages) = jax.lax.scan(
-        group_body, x, (mg, cg, sg, params["lora"], k_pages, v_pages))
+    (x, k_pages, v_pages), (cg2, sg2) = jax.lax.scan(
+        group_body, (x, k_pages, v_pages),
+        (mg, cg, sg, params["lora"], jnp.arange(n_attn, dtype=jnp.int32)))
     mt = jax.tree.map(lambda a: a[n_grouped:], params["mamba"])
     ct0 = jax.tree.map(lambda a: a[n_grouped:], conv_states)
     x, (ct2, st2) = mamba_scan(x, mt, ct0, ssm_states[n_grouped:])

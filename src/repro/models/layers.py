@@ -265,37 +265,45 @@ _flash_ckpt.defvjp(_flash_ckpt_fwd, _flash_ckpt_bwd)
 
 
 # ----------------------------------------------- paged attention (ref) -----
-def gather_pages(pages, block_table):
-    """pages [N, ps, KV, d], block_table [B, Pmax] -> [B, Pmax*ps, KV, d]."""
+def gather_pages(pages, block_table, layer, head_dim):
+    """Each row's pages: block_table [B, Pmax] -> [B, Pmax*ps, KV, head_dim].
+
+    pages is the whole pool [L, N, ps, KV * head_dim] (``init_pages``),
+    read at ``layer`` by one gather: no slab of the layer is taken first,
+    since XLA may copy it. With ``layer`` None, pages is one layer in the
+    Pallas kernels' layout [N, ps, KV, head_dim], for their oracles.
+    """
     B, Pmax = block_table.shape
-    ps = pages.shape[1]
-    g = pages[block_table]                                 # [B, Pmax, ps, KV, d]
-    return g.reshape(B, Pmax * ps, *pages.shape[2:])
+    if layer is None:                   # as a pool of that one layer
+        pages, layer = pages.reshape(*pages.shape[:2], -1)[None], 0
+    g = pages[layer, block_table]       # [B, Pmax, ps, KV * head_dim]
+    return g.reshape(B, Pmax * g.shape[2], g.shape[3] // head_dim, head_dim)
 
 
 def paged_attention_ref(
     q,                 # [B, Tq, H, d] (Tq=1 decode, Tq=chunk prefill)
-    k_pages, v_pages,  # [N, ps, KV, d]
+    k_pages, v_pages,  # whole pools [L, N, ps, KV*d] read at layer;
+                       # one layer [N, ps, KV, d] if layer is None
     block_table,       # [B, Pmax] int32 (local page indices)
     kv_lens,           # [B] valid kv length (incl. freshly written tokens)
     q_positions,       # [B, Tq]
     *,
-    scale, window=None, attn_softcap=None, block_kv=512,
+    scale, layer=None, window=None, attn_softcap=None, block_kv=512,
 ):
     """Reference paged attention: gather pages then flash over them.
 
     Used as the CPU/dry-run implementation and as the oracle for the
     Pallas kernels.
     """
-    B, Pmax = block_table.shape
-    ps = k_pages.shape[1]
-    k = gather_pages(k_pages, block_table)
-    v = gather_pages(v_pages, block_table)
-    kv_pos = jnp.broadcast_to(jnp.arange(Pmax * ps, dtype=jnp.int32)[None], (B, Pmax * ps))
+    d = q.shape[-1]
+    k = gather_pages(k_pages, block_table, layer, d)
+    v = gather_pages(v_pages, block_table, layer, d)
+    B, Tk = k.shape[:2]
+    kv_pos = jnp.broadcast_to(jnp.arange(Tk, dtype=jnp.int32)[None], (B, Tk))
     return flash_attention(
         q, k, v, q_positions=q_positions, kv_positions=kv_pos,
         kv_valid_len=kv_lens, scale=scale, causal=True, window=window,
-        attn_softcap=attn_softcap, block_kv=min(block_kv, Pmax * ps),
+        attn_softcap=attn_softcap, block_kv=min(block_kv, Tk),
     )
 
 

@@ -327,21 +327,23 @@ def prefill(params, cfg, tokens, *, patches=None, tp=1, policy=None,
 
 
 # ------------------------------------------------------------------ decode -
-def write_kv_token(kpg, vpg, k, v, block_table, seq_lens, active=None):
-    """Scatter one new token per sequence into the page pool.
+def write_kv_token(kpg, vpg, layer, k, v, block_table, seq_lens, active):
+    """Scatter one new token per sequence into layer ``layer`` of the pool.
 
-    kpg/vpg [N, ps, KV_p, hd]; k/v [B, KV_p, hd]. The last page of the pool
-    is the trash page for inactive slots (never allocated by the engine).
+    kpg/vpg [L, N, ps, KV_p * hd] (``init_pages``); k/v [B, KV_p, hd],
+    stored as one row per token. The last page of the pool is the trash
+    page for inactive slots (never allocated by the engine). One scatter
+    per pool, in place when the pool is donated or carried.
     """
     with jax.named_scope("kv_write"):
-        ps = kpg.shape[1]
+        ps = kpg.shape[2]
         pidx = jnp.take_along_axis(block_table, (seq_lens // ps)[:, None],
                                    1)[:, 0]
         off = seq_lens % ps
-        if active is not None:
-            trash = kpg.shape[0] - 1
-            pidx = jnp.where(active, pidx, trash)
-        return kpg.at[pidx, off].set(k), vpg.at[pidx, off].set(v)
+        pidx = jnp.where(active, pidx, kpg.shape[1] - 1)
+        row = (pidx.shape[0], kpg.shape[-1])
+        return (kpg.at[layer, pidx, off].set(k.reshape(row)),
+                vpg.at[layer, pidx, off].set(v.reshape(row)))
 
 
 def default_paged_attn(q, kpg, vpg, block_table, kv_lens, q_positions, *,
@@ -354,32 +356,40 @@ def default_paged_attn(q, kpg, vpg, block_table, kv_lens, q_positions, *,
 # Pluggable paged write+attend steps. The WRITE lives inside the pluggable
 # fn so the production path can run it in a shard_map island (GSPMD cannot
 # partition data-dependent page scatters/gathers; see launch/spmd.py).
-def default_decode_attn(q, k_new, v_new, kpg, vpg, block_table, seq_lens,
-                        active, *, scale, window, attn_softcap):
+# Contract, for every implementation: the fn takes the WHOLE pools
+# kpg/vpg [L, N, ps, KV_p * hd] (``init_pages``; pytrees of such leaves
+# in kernels/kv_int8.py) and the scan's layer index, writes the new K/V at
+# [layer, page, offset], attends over that layer's pages through one
+# gather indexed by (layer, block_table), and returns the whole pools.
+# The layer scans carry the pools, so the writes stay in place and no
+# per-layer slab is sliced or restacked.
+def default_decode_attn(q, k_new, v_new, kpg, vpg, layer, block_table,
+                        seq_lens, active, *, scale, window, attn_softcap):
     """q [B,1,H_p,hd]; k_new/v_new [B,KV_p,hd]. Returns (o, kpg, vpg)."""
-    kpg, vpg = write_kv_token(kpg, vpg, k_new, v_new, block_table, seq_lens,
-                              active)
+    kpg, vpg = write_kv_token(kpg, vpg, layer, k_new, v_new, block_table,
+                              seq_lens, active)
     o = paged_attention_ref(q, kpg, vpg, block_table, seq_lens + 1,
-                            seq_lens[:, None], scale=scale, window=window,
-                            attn_softcap=attn_softcap)
+                            seq_lens[:, None], scale=scale, layer=layer,
+                            window=window, attn_softcap=attn_softcap)
     return o, kpg, vpg
 
 
-def default_chunk_attn(q, k_new, v_new, kpg, vpg, block_table, start, lens, *,
-                       scale, window, attn_softcap):
+def default_chunk_attn(q, k_new, v_new, kpg, vpg, layer, block_table, start,
+                       lens, *, scale, window, attn_softcap):
     """q [P,C,H_p,hd]; k_new/v_new [P,C,KV_p,hd]. Returns (o, kpg, vpg)."""
-    kpg, vpg = write_kv_chunk(kpg, vpg, k_new, v_new, block_table, start, lens)
+    kpg, vpg = write_kv_chunk(kpg, vpg, layer, k_new, v_new, block_table,
+                              start, lens)
     C = q.shape[1]
     q_pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
     o = paged_attention_ref(q, kpg, vpg, block_table, start + lens, q_pos,
-                            scale=scale, window=window,
+                            scale=scale, layer=layer, window=window,
                             attn_softcap=attn_softcap)
     return o, kpg, vpg
 
 
 def decode(params, cfg, tokens, k_pages, v_pages, block_table, seq_lens, *,
            active=None, attn_fn=None, tp=1, policy=None, moe_fn=None):
-    """One decode step. tokens [B]; pages [L, N, ps, KV_p, hd].
+    """One decode step. tokens [B]; pages [L, N, ps, KV_p * hd].
 
     attn_fn: a `default_decode_attn`-shaped write+attend step.
     Returns (logits [B, Vp], (k_pages, v_pages)).
@@ -399,8 +409,8 @@ def decode(params, cfg, tokens, k_pages, v_pages, block_table, seq_lens, *,
     act = active if active is not None else jnp.ones((B,), bool)
 
     def body(carry, xs):
-        xc, aux = carry
-        lp, kpg, vpg, win = xs
+        xc, aux, kpg, vpg = carry
+        lp, win, layer = xs
         with jax.named_scope("qkv"):
             h = rms_norm(xc, lp["ln1"], cfg.norm_eps)
             q, k, v = _qkv(cfg, lay, lp, h)        # q [B,H_p,hd]; k/v [B,KV,hd]
@@ -409,8 +419,9 @@ def decode(params, cfg, tokens, k_pages, v_pages, block_table, seq_lens, *,
             k = rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
             ke = _expand_kv(k, kv_map, policy, ("batch", "kv_heads", None))
             ve = _expand_kv(v, kv_map, policy, ("batch", "kv_heads", None))
-            o, kpg, vpg = attn_fn(q, ke, ve, kpg, vpg, block_table, seq_lens,
-                                  act, scale=_attn_scale(cfg), window=win,
+            o, kpg, vpg = attn_fn(q, ke, ve, kpg, vpg, layer, block_table,
+                                  seq_lens, act, scale=_attn_scale(cfg),
+                                  window=win,
                                   attn_softcap=cfg.attn_logit_softcap)
         with jax.named_scope("o_proj"):
             attn_out = _o_proj(cfg, lp, o[:, 0], head_mask)
@@ -425,37 +436,38 @@ def decode(params, cfg, tokens, k_pages, v_pages, block_table, seq_lens, *,
             xc = xc + y
         if policy is not None:
             xc = constrain(xc, policy, "batch", None)
-        return (xc, aux + a), (kpg, vpg)
+        return (xc, aux + a, kpg, vpg), None
 
-    (x, _), (k_pages, v_pages) = jax.lax.scan(
-        body, (x, jnp.float32(0.0)),
-        (params["blocks"], k_pages, v_pages, windows))
+    (x, _, k_pages, v_pages), _ = jax.lax.scan(
+        body, (x, jnp.float32(0.0), k_pages, v_pages),
+        (params["blocks"], windows, jnp.arange(len(windows), dtype=jnp.int32)))
     with jax.named_scope("logits"):
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         return unembed(params, cfg, x, policy), (k_pages, v_pages)
 
 
 # ------------------------------------------------------------------- mixed -
-def write_kv_chunk(kpg, vpg, k, v, block_table, start, lens):
-    """Scatter a prefill chunk into pages.
+def write_kv_chunk(kpg, vpg, layer, k, v, block_table, start, lens):
+    """Scatter a prefill chunk into layer ``layer`` of the pool.
 
-    kpg [N, ps, KV_p, hd]; k/v [P, C, KV_p, hd]; block_table [P, Pmax];
+    kpg [L, N, ps, KV_p * hd]; k/v [P, C, KV_p, hd]; block_table [P, Pmax];
     start [P] first position of chunk; lens [P] valid tokens (rest->trash).
     """
     with jax.named_scope("kv_write"):
         P, C = k.shape[:2]
-        ps = kpg.shape[1]
+        ps = kpg.shape[2]
         j = jnp.arange(C, dtype=jnp.int32)[None]                   # [1, C]
         gpos = start[:, None] + j                                  # [P, C]
         page_slot = gpos // ps
         pidx = jnp.take_along_axis(block_table, page_slot, axis=1) # [P, C]
         off = gpos % ps
-        trash = kpg.shape[0] - 1
+        trash = kpg.shape[1] - 1
         valid = j < lens[:, None]
         pidx = jnp.where(valid, pidx, trash)
-        flat = lambda t: t.reshape((P * C,) + t.shape[2:])
-        kpg = kpg.at[flat(pidx), flat(off)].set(flat(k))
-        vpg = vpg.at[flat(pidx), flat(off)].set(flat(v))
+        pidx, off = pidx.reshape(P * C), off.reshape(P * C)
+        row = (P * C, kpg.shape[-1])
+        kpg = kpg.at[layer, pidx, off].set(k.reshape(row))
+        vpg = vpg.at[layer, pidx, off].set(v.reshape(row))
         return kpg, vpg
 
 
@@ -505,8 +517,8 @@ def mixed(params, cfg, mb, k_pages, v_pages, *, attn_fn=None, tp=1,
     d_pos = mb["d_lens"]
 
     def body(carry, xs):
-        xc, aux = carry
-        lp, kpg, vpg, win = xs
+        xc, aux, kpg, vpg = carry
+        lp, win, layer = xs
         with jax.named_scope("qkv"):
             h = rms_norm(xc, lp["ln1"], cfg.norm_eps)
             q, k, v = _qkv(cfg, lay, lp, h)                    # the shared GEMM
@@ -520,8 +532,9 @@ def mixed(params, cfg, mb, k_pages, v_pages, *, attn_fn=None, tp=1,
             kp = rope(kp, p_pos, cfg.rope_theta)
             kpe = _expand_kv(kp, kv_map)
             vpe = _expand_kv(vp, kv_map)
-            o_p, kpg, vpg = chunk_attn(qp, kpe, vpe, kpg, vpg, mb["p_table"],
-                                       mb["p_start"], mb["p_lens"],
+            o_p, kpg, vpg = chunk_attn(qp, kpe, vpe, kpg, vpg, layer,
+                                       mb["p_table"], mb["p_start"],
+                                       mb["p_lens"],
                                        scale=_attn_scale(cfg), window=win,
                                        attn_softcap=cfg.attn_logit_softcap)
 
@@ -531,8 +544,9 @@ def mixed(params, cfg, mb, k_pages, v_pages, *, attn_fn=None, tp=1,
             kd = rope(kd[:, None], d_pos[:, None], cfg.rope_theta)[:, 0]
             kde = _expand_kv(kd, kv_map)
             vde = _expand_kv(vd, kv_map)
-            o_d, kpg, vpg = decode_attn(qd, kde, vde, kpg, vpg, mb["d_table"],
-                                        mb["d_lens"], mb["d_active"],
+            o_d, kpg, vpg = decode_attn(qd, kde, vde, kpg, vpg, layer,
+                                        mb["d_table"], mb["d_lens"],
+                                        mb["d_active"],
                                         scale=_attn_scale(cfg), window=win,
                                         attn_softcap=cfg.attn_logit_softcap)
 
@@ -551,11 +565,11 @@ def mixed(params, cfg, mb, k_pages, v_pages, *, attn_fn=None, tp=1,
             xc = xc + y
         if policy is not None:
             xc = constrain(xc, policy, "tokens", None)
-        return (xc, aux + a), (kpg, vpg)
+        return (xc, aux + a, kpg, vpg), None
 
-    (x, aux), (k_pages, v_pages) = jax.lax.scan(
-        body, (x, jnp.float32(0.0)),
-        (params["blocks"], k_pages, v_pages, windows))
+    (x, aux, k_pages, v_pages), _ = jax.lax.scan(
+        body, (x, jnp.float32(0.0), k_pages, v_pages),
+        (params["blocks"], windows, jnp.arange(len(windows), dtype=jnp.int32)))
     with jax.named_scope("logits"):
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         xp = x[: P * C].reshape(P, C, D)
@@ -569,17 +583,25 @@ def mixed(params, cfg, mb, k_pages, v_pages, *, attn_fn=None, tp=1,
 # -------------------------------------------------------------- page utils -
 def init_pages(cfg, n_pages, page_size, tp=1, dtype=jnp.float32,
                n_layers=None):
+    """Zeroed pools [L, N, ps, KV_p * hd]: a token's heads in one row.
+
+    The row is flat so that a TPU tiles a page's (ps, KV_p * hd) without
+    padding whenever KV_p * hd is a multiple of 128, whatever hd is. With
+    (KV_p, hd) as the minor dims and hd 64 (opt-125m), the v5e compiler
+    lays the pool out with the page dim innermost, and the layer scan
+    copies the whole pool into another layout for its writes and gathers.
+    """
     _, KV_p, _, _, _ = gqa_layout(cfg.n_heads, cfg.n_kv_heads, tp)
     L = n_layers if n_layers is not None else cfg.n_layers
-    shape = (L, n_pages, page_size, KV_p, cfg.head_dim)
+    shape = (L, n_pages, page_size, KV_p * cfg.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
 def kv_to_pages(kv, page_size):
-    """Prefill output [L, B, S, KV_p, hd] -> pages [L, B*S/ps, ps, KV_p, hd]."""
+    """Prefill output [L, B, S, KV_p, hd] -> pages [L, B*S/ps, ps, KV_p * hd]."""
     L, B, S, KVp, hd = kv.shape
     if S % page_size:
         raise ValueError(
             f"prefill length S={S} must be page-aligned (page_size="
             f"{page_size}); callers pad the token batch to whole pages")
-    return kv.reshape(L, B * (S // page_size), page_size, KVp, hd)
+    return kv.reshape(L, B * (S // page_size), page_size, KVp * hd)
