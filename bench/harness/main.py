@@ -24,7 +24,7 @@ from typing import List, Optional
 
 import numpy as np
 
-from harness import check, costs, trace
+from harness import check, trace
 from harness.driver import Driver, Spans
 from harness.spec import Bench, Cell, SpecError
 from harness.traffic import Traffic
@@ -32,6 +32,7 @@ from harness.traffic import Traffic
 SAMPLE_REQUESTS = 16        # requests the correctness check compares
 MIN_COMPARED = 200          # fewest served tokens a sample may hold
 EXIT_SPEC, EXIT_DEVICE = 2, 3
+# sizes every configuration states at its top level
 MODEL_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
               "d_ff", "vocab_size", "rope_theta", "norm_eps")
 
@@ -84,20 +85,43 @@ class CompileCounter:
         jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 
-def build_engine(jax, cfg: dict, seed: int):
+def model_config(cfg: dict):
+    """The program's ``ModelConfig`` of a configuration: its ``arch``'s,
+    with the sizes of ``MODEL_KEYS`` and every key of the optional
+    ``"model"`` object set on it.  A key of ``"model"`` that is no other
+    field of ``ModelConfig`` is refused."""
+    from repro.configs import get_config
+    base = get_config(cfg["arch"])
+    settings = cfg.get("model") or {}
+    known = {f.name for f in dataclasses.fields(base)} - {"name", *MODEL_KEYS}
+    unknown = sorted(set(settings) - known)
+    if unknown:
+        raise SpecError(f"configuration {cfg.get('name')!r}: model settings "
+                        f"{unknown} are not settable fields of ModelConfig "
+                        f"(sizes of {MODEL_KEYS} go at the top level)")
+    settings = {k: tuple(v) if isinstance(v, list) else v
+                for k, v in settings.items()}
+    return dataclasses.replace(base, **{k: cfg[k] for k in MODEL_KEYS}, **settings)
+
+
+def build_model(cfg: dict):
+    """The program's model of a configuration."""
+    from repro.models.registry import get_model
+    return get_model(cfg["arch"], model_config(cfg))
+
+
+def build_engine(jax, cell: Cell, seed: int):
     import jax.numpy as jnp
 
-    from repro.configs import ServeConfig, get_config
+    from repro.configs import ServeConfig
     from repro.core.engine import Engine
-    from repro.models.registry import get_model
 
     from harness.weights import make_params
-    mcfg = dataclasses.replace(get_config(cfg["arch"]),
-                               **{k: cfg[k] for k in MODEL_KEYS})
-    model = get_model(cfg["arch"], mcfg)
+    cfg = cell.config
+    model = build_model(cfg)
     dtype = jnp.dtype(cfg["dtype"])
     shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), dtype))
-    params = make_params(shapes, cfg, seed)
+    params = make_params(shapes, cfg, seed, cell.family.leaf_std)
     serve = ServeConfig(sanitize_level="off", dispatch_sentinel=False,
                         **cfg["serve"])
     return Engine(model, params, serve), params
@@ -168,7 +192,6 @@ class Context:
     t0: float
     window_s: float
     peaks: dict
-    sizes: dict
     itemsize: int
     trace: Optional[dict]
     calls_in_window: list
@@ -178,11 +201,12 @@ class Context:
         chunks = [(st + (r if useful_only else 0), n - (r if useful_only else 0))
                   for st, n, r in c.chunks if n - (r if useful_only else 0) > 0]
         decodes = [L for L, rec in c.decodes if not (useful_only and rec)]
-        return costs.call_flops(self.sizes, chunks, decodes)
+        return self.cell.family.call_flops(self.cell.config, chunks, decodes)
 
     def call_bytes(self, c) -> int:
-        return costs.call_bytes(self.sizes, [(st, n) for st, n, _ in c.chunks],
-                                [L for L, _ in c.decodes], self.itemsize)
+        return self.cell.family.call_bytes(
+            self.cell.config, [(st, n) for st, n, _ in c.chunks],
+            [L for L, _ in c.decodes], self.itemsize)
 
 
 def end_to_end(cell: Cell, drv: Driver, window_s: float, t_stop: float) -> dict:
@@ -220,10 +244,9 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, traced: bool,
     devs = find_devices(jax, cell.chips, require_tpu)
     dev = devs[0]
     peaks = bench.peaks(dev.device_kind) if require_tpu else bench.peaks("TPU v5 lite")
-    reference = bench.reference(cfg["reference"])
-    reference.check_config(cfg)
+    cell.family.check_config(cfg)
 
-    engine, params = build_engine(jax, cfg, seed)
+    engine, params = build_engine(jax, cell, seed)
     if fault:
         inject(engine, fault, cfg["vocab_size"])
     window_s = float(seconds)
@@ -236,7 +259,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, traced: bool,
     counter = CompileCounter()
     tracer = Tracer(jax, traced)
     pre0 = engine.metrics.n_preempt_events
-    drain_s = float(mix["drain_s"])
+    # a cell whose steps are slow gives its last requests longer to finish
+    drain_s = float(params_cell.get("drain_s", mix["drain_s"]))
 
     if mix["arrivals"] == "poisson":
         tracer.start()
@@ -300,7 +324,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, traced: bool,
     in_trace = calls if red else []
     if traced:
         ctx = Context(cell=cell, driver=drv, t0=t0, window_s=window_s,
-                      peaks=peaks, sizes=costs.sizes_of(cfg),
+                      peaks=peaks,
                       itemsize=np.dtype(cfg["dtype"]).itemsize, trace=red,
                       calls_in_window=calls, calls_in_trace=in_trace)
         for m in cell.per_layer:
@@ -318,7 +342,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, traced: bool,
     sample = check.draw_sample(served, seed, SAMPLE_REQUESTS)
     limit = float(params_cell["logit_gap_limit"])
     min_tokens = int(params_cell.get("min_compared_tokens", MIN_COMPARED))
-    gaps = check.widest_gaps(reference, params, cfg, sample,
+    gaps = check.widest_gaps(cell.family, params, cfg, sample,
                              t_pad=serve["page_size"] * serve["max_pages_per_seq"],
                              a_pad=int(mix["output_tokens"]["max"]),
                              control=control)

@@ -5,15 +5,19 @@ this module turns those names into files under ``bench/``:
 
   configs/<config>.json       sizes, dtype, engine settings, pool size
   traffic/<traffic>.json      parameters of the one traffic generator
-  cells/<workload>.json       what is fixed per cell: offered rate, limits
+  cells/<workload>.json       what is fixed per cell: offered rate, limits,
+                              a drain of its own
   metrics/<name>.py           reader of one per-layer metric; a name with a
                               dot (``step_ms.chat``) falls back to the part
                               before the first dot (``step_ms.py``)
-  references/<name>.py        the plain reference a configuration names
+  references/<name>.py        the module of the family a configuration
+                              names under ``reference``: its plain
+                              reference, weight rules and cost counts
+                              (``FAMILY_API``)
   peaks.json                  device peaks, keyed by ``device_kind``
 
-Adding a model, a mix or a metric adds a file and an entry; nothing here
-changes.
+Adding a model, a family, a mix or a metric adds a file and an entry;
+nothing here changes.
 """
 from __future__ import annotations
 
@@ -21,7 +25,17 @@ import importlib.util
 import json
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Callable, Dict, List
+from typing import Any, Callable, Dict, List
+
+# What a family module supplies, beside its plain reference:
+#   check_config(config)                  refuse a configuration it does not implement
+#   logits_at(params, config, tokens, at, quant=None)
+#                                         f32 logits of one sequence at positions ``at``
+#   leaf_std(name, shape, config)         the standard deviation a weight is drawn at
+#   call_flops(config, chunks, decodes)   useful FLOPs of one mixed call
+#   call_bytes(config, chunks, decodes, itemsize)
+#                                         least HBM bytes of one mixed call
+FAMILY_API = ("check_config", "logits_at", "leaf_std", "call_flops", "call_bytes")
 
 
 class SpecError(RuntimeError):
@@ -54,6 +68,7 @@ class Cell:
     cell: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    family: Any = None              # the module the configuration names
     readers: Dict[str, Callable] = field(default_factory=dict)
 
 
@@ -102,8 +117,16 @@ class Bench:
                         f"under {mdir}")
 
     def reference(self, name: str):
-        return _load_module(self.dir / "references" / f"{name}.py",
-                            f"bench_reference_{name}")
+        """The family module ``references/<name>.py``, refused where it
+        lacks any function of ``FAMILY_API``."""
+        path = self.dir / "references" / f"{name}.py"
+        if not path.is_file():
+            raise SpecError(f"missing file {path}")
+        mod = _load_module(path, f"bench_reference_{name}")
+        missing = [f for f in FAMILY_API if not callable(getattr(mod, f, None))]
+        if missing:
+            raise SpecError(f"{path} defines no {', '.join(missing)}")
+        return mod
 
     def peaks(self, device_kind: str) -> dict:
         table = _load_json(self.dir / "peaks.json")
@@ -126,11 +149,12 @@ class Bench:
     def cell(self, workload: str) -> Cell:
         w = self.workload(workload)
         e2e, per = self.metrics_of(workload)
-        c = Cell(name=workload, chips=int(w["chips"]),
-                 config=self.config(w["config"]),
+        config = self.config(w["config"])
+        c = Cell(name=workload, chips=int(w["chips"]), config=config,
                  traffic=self.traffic(w["traffic"]),
                  cell=self.cell_params(workload),
-                 end_to_end=e2e, per_layer=per)
+                 end_to_end=e2e, per_layer=per,
+                 family=self.reference(config["reference"]))
         c.readers = {m["name"]: self.reader(m["name"]) for m in per}
         return c
 

@@ -5,20 +5,13 @@ initialiser is only asked for the shapes of its parameter tree
 (``jax.eval_shape``), and every leaf is drawn here, in the type it is
 served in.  The reference reads the same arrays by leaf name.
 
-Scales follow the usual pre-norm recipe: a projection from width ``n``
-has standard deviation ``1 / sqrt(n)``, the two projections back into the
-residual stream are further scaled by ``1 / sqrt(2 * layers)``, and the
-output head takes 0.02.  Embeddings take 1, not the usual 0.02: at 0.02
-the token read is lost under the layers' sum, and a deep random model puts
-its first choice on the same few tokens whatever the context (rank
-collapse), so its greedy output would not depend on the attention or the
-cache the check is there to test.  Norm gains are ``0.1 * N(0, 1)``
-rather than zero, so that the reference has to apply them as the program
-does.
+Each leaf is ``N(0, 1)`` times the standard deviation that the family's
+``leaf_std(name, shape, config)`` gives it (``bench/references/<name>.py``);
+the keys are split from the seed in the tree's flattened order.
 """
 from __future__ import annotations
 
-import math
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -34,30 +27,12 @@ def leaf_name(path) -> str:
     return "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
 
 
-def leaf_std(name: str, shape, sizes: dict) -> float:
-    L = sizes["n_layers"]
-    out = 1.0 / math.sqrt(2 * L)
-    last = name.rsplit("/", 1)[-1]
-    if last == "embed":
-        return 1.0
-    if last == "head":
-        return 0.02
-    if last.startswith("ln"):
-        return 0.1
-    if last in ("wq", "wk", "wv", "w_in"):
-        return 1.0 / math.sqrt(shape[1])                    # [L, D, ...]
-    if last == "wo":
-        return out / math.sqrt(shape[1] * shape[2])         # [L, H, hd, D]
-    if last == "w_out":
-        return out / math.sqrt(shape[1])                    # [L, F, D]
-    raise ValueError(f"no initialisation rule for parameter {name!r}")
-
-
-def make_params(shapes, sizes: dict, seed: int):
+def make_params(shapes, config: dict, seed: int, leaf_std: Callable):
     """Arrays shaped and typed like ``shapes`` (a tree of
-    ``ShapeDtypeStruct``), drawn from ``seed`` on the default device."""
+    ``ShapeDtypeStruct``), drawn from ``seed`` on the default device by
+    the rule ``leaf_std``."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    rules = [(leaf_std(leaf_name(p), s.shape, sizes), s.shape, s.dtype)
+    rules = [(leaf_std(leaf_name(p), s.shape, config), s.shape, s.dtype)
              for p, s in flat]
 
     def draw(key):

@@ -14,7 +14,7 @@ runs.
 from __future__ import annotations
 
 import argparse
-import dataclasses
+import math
 import os
 import sys
 from pathlib import Path
@@ -35,16 +35,16 @@ def main():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from harness.main import MODEL_KEYS
+    from harness.main import build_model
     from harness.spec import Bench
-    from repro.configs import ServeConfig, get_config
+    from repro.configs import ServeConfig
     from repro.core.engine import step_program_args
     from repro.core.sampler import greedy_tokens
-    from repro.models.registry import get_model
     from repro.models.transformer import pad_vocab
 
     jax.config.update("jax_enable_compilation_cache", False)
-    cfg = Bench(ROOT).config(args.config)
+    bench = Bench(ROOT)
+    cfg = bench.config(args.config)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
 
@@ -52,9 +52,7 @@ def main():
         return jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
 
-    mcfg = dataclasses.replace(get_config(cfg["arch"]),
-                               **{k: cfg[k] for k in MODEL_KEYS})
-    model = get_model(cfg["arch"], mcfg)
+    model = build_model(cfg)
     dtype = jnp.dtype(cfg["dtype"])
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), dtype))
     s = cfg["serve"]
@@ -66,7 +64,7 @@ def main():
         print(f"sampler rows={rows}: temp={ma.temp_size_in_bytes}")
     for n_pages in args.pages:
         serve = ServeConfig(**dict(s, n_pages=n_pages))
-        progs = step_program_args(mcfg, serve, params, prefill_batch=1,
+        progs = step_program_args(model.cfg, serve, params, prefill_batch=1,
                                   prefill_len=s["page_size"])
         fn, fargs = progs["mixed_chunked"]
         try:
@@ -74,7 +72,9 @@ def main():
         except Exception as e:          # the compiler's refusal is the answer
             print(f"pages={n_pages}: refused: {str(e).splitlines()[0][:300]}")
             continue
-        pool = 2 * int(jnp.prod(jnp.array(fargs[2].shape))) * dtype.itemsize
+        # the K and V pools as the program allocates them
+        pool = sum(math.prod(a.shape) * a.dtype.itemsize
+                   for a in jax.tree.leaves((fargs[2], fargs[3])))
         foot = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                 - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
         print(f"pages={n_pages}: pool={pool} argument={ma.argument_size_in_bytes} "

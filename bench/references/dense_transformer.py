@@ -23,14 +23,55 @@ in the weight GEMMs (projections, MLP, unembedding) weights per output
 column and activations per row; in attention q and k per head vector, v per
 (head, dim) column over the keys, and the softmax weights per query row.
 Accumulation stays float32.
+
+The module is also the dense family's part of the harness (see
+``bench/harness/spec.py`` ``FAMILY_API``): the rule each weight is drawn
+by (``leaf_std``) and the useful FLOPs and least bytes of a mixed call
+(``call_flops``, ``call_bytes``).
+
+Weight scales follow the usual pre-norm recipe: a projection from width
+``n`` has standard deviation ``1 / sqrt(n)``, the two projections back
+into the residual stream are further scaled by ``1 / sqrt(2 * layers)``,
+and the output head takes 0.02.  Embeddings take 1, not the usual 0.02:
+at 0.02 the token read is lost under the layers' sum, and a deep random
+model puts its first choice on the same few tokens whatever the context
+(rank collapse), so its greedy output would not depend on the attention
+or the cache the check is there to test.  Norm gains are
+``0.1 * N(0, 1)`` rather than zero, so that the reference has to apply
+them as the program does.
+
+FLOPs of a call, counted from the configuration's sizes and the rows the
+call really served, never from the program's padded shapes:
+
+  * every real row (prefill chunk token or active decode token) passes
+    the layer GEMMs once: ``2 * (D*H*hd + 2*D*KV*hd + H*hd*D + 2*D*F)``
+    per layer (q, k and v, o, and the two MLP matrices);
+  * attention at live lengths: a query at position ``p`` attends ``p + 1``
+    keys, which costs ``4 * H * hd * (p + 1)`` per layer (scores and the
+    weighted sum);
+  * the unembedding, ``2 * D * V``, for each row whose logits the step
+    samples from: every active decode row and every stream that ran a
+    chunk (the program computes one logits row per such stream).
+
+Bytes, the least a perfect program moves through HBM:
+
+  * every weight once: layer weights, norms, the output head (its
+    ``V`` real rows), and the embedding rows of the real tokens;
+  * the live KV read once: ``start`` history tokens for a chunk,
+    ``seq_len`` for a decode row;
+  * the new KV written once: ``n`` tokens for a chunk, one for a decode.
 """
 from __future__ import annotations
 
 import functools
+import math
+from typing import Iterable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from harness.costs import Chunk, chunk_keys
 
 F8_MAX = 448.0        # largest finite float8_e4m3fn
 
@@ -140,3 +181,75 @@ def logits_at(params, config: dict, tokens, at, quant=None):
         x = _layer(x, lp, sizes, quant)
     return _head(x, jnp.asarray(at, jnp.int32), params["ln_f"],
                  params.get("head", params["embed"]), sizes, quant)
+
+
+# ------------------------------------------------------ the weights' rules --
+def leaf_std(name: str, shape, config: dict) -> float:
+    """Standard deviation of the leaf ``name`` (``blocks/wq``, ...)."""
+    L = config["n_layers"]
+    out = 1.0 / math.sqrt(2 * L)
+    last = name.rsplit("/", 1)[-1]
+    if last == "embed":
+        return 1.0
+    if last == "head":
+        return 0.02
+    if last.startswith("ln"):
+        return 0.1
+    if last in ("wq", "wk", "wv", "w_in"):
+        return 1.0 / math.sqrt(shape[1])                    # [L, D, ...]
+    if last == "wo":
+        return out / math.sqrt(shape[1] * shape[2])         # [L, H, hd, D]
+    if last == "w_out":
+        return out / math.sqrt(shape[1])                    # [L, F, D]
+    raise ValueError(f"no initialisation rule for parameter {name!r}")
+
+
+# --------------------------------------------------- a call's FLOPs, bytes --
+def gemm_flops_per_row(s: dict) -> int:
+    D, H, KV, hd, F = (s["d_model"], s["n_heads"], s["n_kv_heads"],
+                       s["head_dim"], s["d_ff"])
+    return 2 * (D * H * hd + 2 * D * KV * hd + H * hd * D + 2 * D * F) * s["n_layers"]
+
+
+def attn_flops(s: dict, keys: int) -> int:
+    """FLOPs of queries that attend ``keys`` keys in total."""
+    return 4 * s["n_heads"] * s["head_dim"] * keys * s["n_layers"]
+
+
+def unembed_flops(s: dict) -> int:
+    return 2 * s["d_model"] * s["vocab_size"]
+
+
+def call_flops(s: dict, chunks: Iterable[Chunk], decodes: Iterable[int]) -> int:
+    """Useful FLOPs of one call: ``chunks`` of (start, n), decode rows of
+    kv length ``seq_len`` (the new token sits at position ``seq_len``)."""
+    chunks, decodes = list(chunks), list(decodes)
+    rows = sum(n for _, n in chunks) + len(decodes)
+    keys = (sum(chunk_keys(st, n) for st, n in chunks)
+            + sum(L + 1 for L in decodes))
+    logit_rows = len(chunks) + len(decodes)
+    return (rows * gemm_flops_per_row(s) + attn_flops(s, keys)
+            + logit_rows * unembed_flops(s))
+
+
+def weight_bytes(s: dict, itemsize: int) -> int:
+    D, H, KV, hd, F, L, V = (s["d_model"], s["n_heads"], s["n_kv_heads"],
+                             s["head_dim"], s["d_ff"], s["n_layers"],
+                             s["vocab_size"])
+    per_layer = D * H * hd + 2 * D * KV * hd + H * hd * D + 2 * D * F + 2 * D
+    return (L * per_layer + V * D + D) * itemsize
+
+
+def _kv_bytes_per_token(s: dict, itemsize: int) -> int:
+    """K and V of one token over every layer."""
+    return 2 * s["n_kv_heads"] * s["head_dim"] * s["n_layers"] * itemsize
+
+
+def call_bytes(s: dict, chunks: Iterable[Chunk], decodes: Iterable[int],
+               itemsize: int) -> int:
+    chunks, decodes = list(chunks), list(decodes)
+    rows = sum(n for _, n in chunks) + len(decodes)
+    kv_read = sum(st for st, _ in chunks) + sum(decodes)
+    kv_write = rows
+    return (weight_bytes(s, itemsize) + rows * s["d_model"] * itemsize
+            + (kv_read + kv_write) * _kv_bytes_per_token(s, itemsize))

@@ -74,7 +74,7 @@ def main():
     cfg, mix = cell.config, cell.traffic
     jax = setup_jax(ROOT)
     find_devices(jax, cell.chips, True)
-    engine, _ = build_engine(jax, cfg, args.seed)
+    engine, _ = build_engine(jax, cell, args.seed)
     Path(args.out).mkdir(exist_ok=True)
     out = open(Path(args.out) / f"sweep-{args.workload}.jsonl", "a")
     cap = probe_capacity(engine, cfg, mix)

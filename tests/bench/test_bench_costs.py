@@ -1,17 +1,22 @@
-"""Useful FLOPs and least bytes of one mixed call, against hand counts
-for opt-125m (12 layers x 768, 12 heads x 64, d_ff 3072, vocab 50,272)."""
-import benchroot  # noqa: F401  (puts bench/ on the path)
+"""Useful FLOPs and least bytes of one mixed call, as the dense family
+counts them (``bench/references/dense_transformer.py``), against hand
+counts for opt-125m (12 layers x 768, 12 heads x 64, d_ff 3072, vocab
+50,272)."""
+from benchroot import REPO
 from harness import costs
+from harness.spec import Bench
+
+dense = Bench(REPO).reference("dense_transformer")
 
 # opt-125m's published sizes (huggingface.co/facebook/opt-125m)
-OPT = costs.sizes_of({"n_layers": 12, "d_model": 768, "n_heads": 12, "n_kv_heads": 12,
-                      "head_dim": 64, "d_ff": 3072, "vocab_size": 50272})
+OPT = {"n_layers": 12, "d_model": 768, "n_heads": 12, "n_kv_heads": 12,
+       "head_dim": 64, "d_ff": 3072, "vocab_size": 50272}
 
 
 def test_gemm_flops_per_row_by_hand():
     # q, k, v, o: 4 * 768 * 768; MLP: 2 * 768 * 3072; two FLOPs a MAC
     per_layer = 2 * (4 * 768 * 768 + 2 * 768 * 3072)
-    assert costs.gemm_flops_per_row(OPT) == 12 * per_layer == 169_869_312
+    assert dense.gemm_flops_per_row(OPT) == 12 * per_layer == 169_869_312
 
 
 def test_one_step_by_hand():
@@ -22,13 +27,13 @@ def test_one_step_by_hand():
     attn = 4 * 12 * 64 * keys * 12
     unembed = 3 * 2 * 768 * 50272
     want = rows * 169_869_312 + attn + unembed
-    assert costs.call_flops(OPT, chunks, decodes) == want
+    assert dense.call_flops(OPT, chunks, decodes) == want
 
     layer_w = 4 * 768 * 768 + 2 * 768 * 3072 + 2 * 768
     weights = (12 * layer_w + 50272 * 768 + 768) * 2
     kv_tok = 2 * 12 * 64 * 12 * 2               # k and v, 12 layers, bf16
     moved = weights + rows * 768 * 2 + (512 + 1040 + rows) * kv_tok
-    assert costs.call_bytes(OPT, chunks, decodes, 2) == moved
+    assert dense.call_bytes(OPT, chunks, decodes, 2) == moved
 
 
 def test_ideal_time_names_its_bound():

@@ -77,7 +77,7 @@ def test_a_pause_after_the_window_holds_the_schedule(tiny_root):
     from harness.spec import Bench
     from harness.traffic import Traffic
     cell = Bench(tiny_root).cell("tiny.chat")
-    engine, _ = build_engine(setup_jax(tiny_root), cell.config, 3)
+    engine, _ = build_engine(setup_jax(tiny_root), cell, 3)
     drv = Driver(engine, Traffic(cell.traffic, 3, cell.config["vocab_size"],
                                  window_s=1.0, rate=8.0), spans=Spans(False))
     drv.warm(8)
@@ -93,3 +93,22 @@ def test_a_pause_after_the_window_holds_the_schedule(tiny_root):
     late = [lag for rec, lag in zip(drv.reqs.values(), drv.lags)
             if rec.submit > paused[0]]
     assert all(lag < 0.5 for lag in late)
+
+
+@pytest.mark.parametrize("workload,method", [("tiny.chat", "open_loop"),
+                                             ("tiny.backlog", "backlog")])
+def test_a_cell_may_give_its_own_drain(tmp_path, monkeypatch, workload, method):
+    """A cell file's ``drain_s`` takes the place of its mix's."""
+    from harness.driver import Driver
+    root = make_tiny_root(tmp_path)
+    path = root / "bench" / "cells" / f"{workload}.json"
+    path.write_text(json.dumps(dict(json.loads(path.read_text()), drain_s=33.0)))
+    seen = []
+    drive = getattr(Driver, method)
+
+    def spy(self, window_s, drain_s, on_window=None):
+        seen.append(drain_s)
+        return drive(self, window_s, drain_s, on_window=on_window)
+    monkeypatch.setattr(Driver, method, spy)
+    r = run(root, workload, 2**31 + 9)
+    assert seen == [33.0] and r["correct"] is True

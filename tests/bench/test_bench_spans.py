@@ -40,7 +40,7 @@ def _driven(root, kind, seed):
     bench = Bench(root)
     cell = bench.cell(f"tiny.{kind}")
     mix, serve = cell.traffic, cell.config["serve"]
-    engine, _ = build_engine(setup_jax(root), cell.config, seed)
+    engine, _ = build_engine(setup_jax(root), cell, seed)
     drv = Driver(engine, Traffic(mix, seed, cell.config["vocab_size"],
                                  window_s=WINDOW_S,
                                  rate=float(cell.cell.get("rate_per_s", 0.0))),
