@@ -163,9 +163,10 @@ def phase_reference(model):
     cfg, serve = model.cfg, serve_config()
     ps, W, R = serve.page_size, serve.max_pages_per_seq, serve.max_batch
     params = model.init(jax.random.PRNGKey(SEED), jnp.bfloat16)
-    progs = build_step_programs(cfg, serve.kv_dtype)
     n_pages, _ = pool_layout(cfg, serve, jnp.bfloat16)
     kpg, vpg = init_pool(cfg, serve, n_pages, jnp.bfloat16)
+    progs = build_step_programs(cfg, serve.kv_dtype,
+                                kpg.devices().pop().platform)
 
     rng = np.random.default_rng(SEED + 1)
     lens = np.linspace(PROMPT_LEN, PROMPT_LEN // 4, R).astype(np.int32)

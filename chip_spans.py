@@ -12,6 +12,8 @@ dispatching steps:
   longest_steps   the ten longest steps, each with its longest child span
                   and the ``host.gc`` time inside it
 
+and, over the whole run, ``decode_page_share`` from the engine's summary.
+
 With ``--trace 1 --profile 1`` it also reads the profile the harness took,
 before the harness deletes it:
 
@@ -233,6 +235,8 @@ def run(root: Path, workload: str, seed: int, seconds: float, traced: bool,
     return {"workload": workload, "seed": seed, "trace": int(traced),
             "result": result,
             "step_ms": sum(steps) / len(steps) * 1e3 if steps else None,
+            "decode_page_share": seen["metrics"].summary()[
+                "decode_page_share"],
             **span_stats(seen["metrics"], seen["t0"], seen["window_s"]),
             "profile": seen.get("profile")}
 

@@ -76,6 +76,7 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Callable, Iterator, List, NamedTuple, Optional
 
 import jax
@@ -88,6 +89,7 @@ from repro.configs.base import ServeConfig
 from repro.kernels.kv_int8 import (fake_quant_kv, init_pages_int8,
                                    int8_chunk_attn, int8_decode_attn,
                                    kv_page_bytes, quant_kv)
+from repro.kernels.ops import paged_decode_attn
 from repro.core.kv_cache import (KVQuantSidecar, PageAllocator,
                                  pool_pages_from_bytes)
 from repro.core.metrics import EngineMetrics, EventRing
@@ -111,7 +113,8 @@ class StepPrograms(NamedTuple):
                         #   d_logits, pages, aux)
 
 
-def build_step_programs(cfg, kv_dtype: str) -> StepPrograms:
+def build_step_programs(cfg, kv_dtype: str, platform: str) -> StepPrograms:
+    """The programs for a pool in ``kv_dtype`` on a ``platform`` device."""
     int8 = kv_dtype == "int8"
 
     # full prefill returning per-row last-token logits; in int8 mode
@@ -129,11 +132,19 @@ def build_step_programs(cfg, kv_dtype: str) -> StepPrograms:
         return T.unembed(params, cfg, hl), kv
     # int8 routing: prefill-commit QUANTIZES (fp paged KV -> codes +
     # per-(token,head) scale written side by side), decode/mixed
-    # DEQUANTIZE in-kernel via the pluggable attn fns; fp path is the
-    # seed behaviour, byte for byte.
-    attn_decode = int8_decode_attn if int8 else None
-    attn_mixed = ({"decode": int8_decode_attn, "chunk": int8_chunk_attn}
-                  if int8 else None)
+    # DEQUANTIZE in-kernel via the pluggable attn fns.  fp pools on a TPU
+    # decode through the Pallas kernel, which reads a page as one
+    # (page_size, KV_p * hd) block: the chip tiles that only in whole
+    # 128-lane rows.  Elsewhere the reference serves, byte for byte.
+    _, KV_p, _, _, _ = T.gqa_layout(cfg.n_heads, cfg.n_kv_heads)
+    if int8:
+        attn_decode = int8_decode_attn
+        attn_mixed = {"decode": int8_decode_attn, "chunk": int8_chunk_attn}
+    elif platform == "tpu" and KV_p * cfg.head_dim % 128 == 0:
+        attn_decode = partial(paged_decode_attn, interpret=False)
+        attn_mixed = {"decode": attn_decode}
+    else:
+        attn_decode = attn_mixed = None
 
     def commit(kpg, vpg, k_new, v_new, dest):
         # k_new [L, M, ps, KV_p * hd] (``kv_to_pages``); dest [M] page ids
@@ -191,9 +202,10 @@ def step_program_args(cfg, serve: ServeConfig, params, *, prefill_batch: int,
     prefill batches of ``prefill_batch`` prompts padded to
     ``prefill_len`` tokens.  ``params`` may be abstract
     (``jax.eval_shape`` of ``Model.init``): nothing is allocated, so the
-    programs lower for a chip that is only described.  The shapes mirror
-    ``_prefill_full_batch``, ``_do_decode`` and ``_dispatch_mixed``."""
-    progs = build_step_programs(cfg, serve.kv_dtype)
+    programs lower for a chip that is only described, and are built for
+    a TPU.  The shapes mirror ``_prefill_full_batch``, ``_do_decode`` and
+    ``_dispatch_mixed``."""
+    progs = build_step_programs(cfg, serve.kv_dtype, "tpu")
     dtype = jax.tree.leaves(params)[0].dtype
     n_pages, _ = pool_layout(cfg, serve, dtype)
     kpg, vpg = jax.eval_shape(lambda: init_pool(cfg, serve, n_pages, dtype))
@@ -376,7 +388,9 @@ class Engine:
 
     # ------------------------------------------------------------- jits ----
     def _build_jits(self):
-        progs = build_step_programs(self.cfg, self.serve.kv_dtype)
+        pool_device = jax.tree.leaves(self.k_pages)[0].devices().pop()
+        progs = build_step_programs(self.cfg, self.serve.kv_dtype,
+                                    pool_device.platform)
         # prefill/commit batches legitimately vary with workload shape, so
         # the sentinel only counts them; decode/mixed/samplers are the
         # steady-state step loop where any compile density is a bug.
@@ -843,7 +857,7 @@ class Engine:
                 self.metrics.n_prefill_tokens += len(toks)
         with span("engine.dispatch", self._mixed_rows(
                 [(n, len(sfx)) for (_, n), sfx in zip(hits, suffixes)],
-                [r.rid for r, _ in hits], [], [], P * C)):
+                [r.rid for r, _ in hits], [], [], P * C, 0)):
             mb = dict(
                 p_tokens=jnp.asarray(p_tokens),
                 p_table=jnp.asarray(p_table),
@@ -1110,18 +1124,26 @@ class Engine:
         return tokens, lens, active
 
     def _mixed_rows(self, chunks, rids, decodes, decode_rids,
-                    rows_program: int) -> dict:
+                    rows_program: int, decode_rows: int) -> dict:
         """Attributes of a mixed ``engine.dispatch`` span, and the running
-        row totals: the served chunks as ``(start, n)``, the decode rows'
-        sequence lengths, the rids of both (chunks first), the live rows
-        (chunk tokens + decode rows) and the rows of the program's static
-        shape."""
+        row and page totals: the served chunks as ``(start, n)``, the
+        decode rows' sequence lengths, the rids of both (chunks first),
+        the live rows (chunk tokens + decode rows), the rows of the
+        program's static shape, and the pages decode attention reads
+        (each live decode row's, with this step's token) against the
+        tables of the program's ``decode_rows``."""
         live = sum(n for _, n in chunks) + len(decodes)
-        self.metrics.rows_live += live
-        self.metrics.rows_program += rows_program
+        ps = self.serve.page_size
+        pages = sum(n // ps + 1 for n in decodes)      # cdiv(n + 1, ps)
+        m = self.metrics
+        m.rows_live += live
+        m.rows_program += rows_program
+        m.decode_pages_live += pages
+        m.decode_pages_table += decode_rows * self.serve.max_pages_per_seq
         return {"program": "mixed", "rows_live": live,
-                "rows_program": rows_program, "chunks": chunks,
-                "decodes": decodes, "rids": rids + decode_rids}
+                "rows_program": rows_program, "decode_pages": pages,
+                "chunks": chunks, "decodes": decodes,
+                "rids": rids + decode_rids}
 
     def _dispatch_mixed(self, plan: Optional[ChunkPlan],
                         with_decode: bool) -> Optional[list]:
@@ -1153,7 +1175,7 @@ class Engine:
                 [(st.pos, n) for _, st, n in chunks],
                 [st.req.rid for _, st, _ in chunks],
                 [s.seq_len for s in decoding], [s.req.rid for s in decoding],
-                p_tokens.size + d_active.size)):
+                p_tokens.size + d_active.size, d_active.size)):
             if with_decode:
                 d_half = dict(
                     d_tokens=jnp.asarray(d_tokens),

@@ -234,6 +234,11 @@ class EngineMetrics:
     # every mixed dispatch
     rows_live: int = 0
     rows_program: int = 0
+    # pages decode attention reads in the mixed dispatches (each live
+    # decode row's cdiv(seq_len + 1, page_size)) against the pages of
+    # the program's full decode tables (max_batch x max_pages_per_seq)
+    decode_pages_live: int = 0
+    decode_pages_table: int = 0
 
     def __post_init__(self):
         # the span recorder's own state, not telemetry
@@ -360,6 +365,10 @@ class EngineMetrics:
             # live rows of the mixed program over the rows it computes
             "row_use": (self.rows_live / self.rows_program
                         if self.rows_program else None),
+            # share of the decode tables' pages that hold live KV
+            "decode_page_share": (
+                self.decode_pages_live / self.decode_pages_table
+                if self.decode_pages_table else None),
             "spans_dropped": self.spans.n_dropped,
             # SLO outcomes: only requests carrying a deadline count, so
             # attainment is None (not a vacuous 1.0) on deadline-free runs

@@ -1,7 +1,7 @@
 """Pallas TPU kernel: ragged paged attention over int8 KV pages.
 
-Same grid/tiling as ``kernels/paged_attention.py`` — one kernel for both
-inference phases (decode C=1, chunked prefill C=chunk) — but the K/V page
+One kernel for both inference phases (decode C=1, chunked prefill
+C=chunk), one page per grid step of a one-layer pool; the K/V page
 pool streams through VMEM as int8 codes plus an f32 per-(token, head)
 scale sidecar, and dequantization happens *inside* the kernel right
 before the MXU matmuls.  HBM traffic per page drops from ``ps*KV_p*hd``
@@ -11,7 +11,7 @@ reduction, on top of the equal-bytes capacity win the byte-denominated
 allocator takes.
 
 Layout:
-  q         [B, KV_p, C, G, d]  fp (padded layout, as the fp kernel)
+  q         [B, KV_p, C, G, d]  fp (padded layout)
   k_pages   [N, ps, KV_p, d]    int8 codes
   k_scales  [N, ps, KV_p, 1]    f32 (sidecar rides the same page table;
                                 on TPU the unit lane is tolerable — the

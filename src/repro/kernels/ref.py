@@ -12,16 +12,14 @@ from repro.models.layers import flash_attention as _flash_ref
 from repro.models.layers import paged_attention_ref as _paged_ref
 
 
-def paged_attention_ref(q, k_pages, v_pages, block_table, kv_lens, q_pos, *,
-                        scale, window=None, softcap=None):
-    """q [B, KV_p, C, G, d] (kernel layout) -> o same shape."""
-    B, KV_p, C, G, d = q.shape
-    # kernel layout -> model layout [B, C, H_p, d] with H_p = KV_p * G
-    qm = q.transpose(0, 2, 1, 3, 4).reshape(B, C, KV_p * G, d)
-    q_positions = q_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
-    o = _paged_ref(qm, k_pages, v_pages, block_table, kv_lens, q_positions,
-                   scale=scale, window=window, attn_softcap=softcap)
-    return o.reshape(B, C, KV_p, G, d).transpose(0, 2, 1, 3, 4)
+def paged_decode_attention_ref(q, k_pages, v_pages, layer, block_table,
+                               kv_lens, window, *, scale, softcap=None):
+    """q [B, H_p, d]; whole pools [L, N, ps, KV_p * d] read at ``layer``;
+    each row's one query sits at position ``kv_lens - 1``. -> [B, H_p, d]."""
+    o = _paged_ref(q[:, None], k_pages, v_pages, block_table, kv_lens,
+                   (kv_lens - 1)[:, None], scale=scale, layer=layer,
+                   window=window, attn_softcap=softcap)
+    return o[:, 0]
 
 
 def flash_attention_ref(q, k, v, kv_lens, *, scale, causal=True, window=None,

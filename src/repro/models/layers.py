@@ -271,7 +271,7 @@ def gather_pages(pages, block_table, layer, head_dim):
     pages is the whole pool [L, N, ps, KV * head_dim] (``init_pages``),
     read at ``layer`` by one gather: no slab of the layer is taken first,
     since XLA may copy it. With ``layer`` None, pages is one layer in the
-    Pallas kernels' layout [N, ps, KV, head_dim], for their oracles.
+    int8 Pallas kernel's layout [N, ps, KV, head_dim], for its oracle.
     """
     B, Pmax = block_table.shape
     if layer is None:                   # as a pool of that one layer

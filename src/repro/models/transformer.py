@@ -346,21 +346,16 @@ def write_kv_token(kpg, vpg, layer, k, v, block_table, seq_lens, active):
                 vpg.at[layer, pidx, off].set(v.reshape(row)))
 
 
-def default_paged_attn(q, kpg, vpg, block_table, kv_lens, q_positions, *,
-                       scale, window, attn_softcap):
-    return paged_attention_ref(q, kpg, vpg, block_table, kv_lens, q_positions,
-                               scale=scale, window=window,
-                               attn_softcap=attn_softcap)
-
-
 # Pluggable paged write+attend steps. The WRITE lives inside the pluggable
 # fn so the production path can run it in a shard_map island (GSPMD cannot
 # partition data-dependent page scatters/gathers; see launch/spmd.py).
 # Contract, for every implementation: the fn takes the WHOLE pools
 # kpg/vpg [L, N, ps, KV_p * hd] (``init_pages``; pytrees of such leaves
 # in kernels/kv_int8.py) and the scan's layer index, writes the new K/V at
-# [layer, page, offset], attends over that layer's pages through one
-# gather indexed by (layer, block_table), and returns the whole pools.
+# [layer, page, offset], attends over that layer's pages (the reference
+# through one gather indexed by (layer, block_table), the Pallas decode
+# kernel in kernels/ops.py by reading each live page in place), and
+# returns the whole pools.
 # The layer scans carry the pools, so the writes stay in place and no
 # per-layer slab is sliced or restacked.
 def default_decode_attn(q, k_new, v_new, kpg, vpg, layer, block_table,

@@ -82,16 +82,22 @@ def programs(on_chip):
 
 
 @pytest.fixture(scope="module")
-def memory(programs):
-    """``memory_analysis()`` of each program, compiled once."""
+def compiled(programs):
+    """Each program compiled once."""
     cache = {}
 
     def get(name, kv_dtype="fp"):
         if (name, kv_dtype) not in cache:
             fn, args = programs(name, kv_dtype)
-            cache[name, kv_dtype] = fn.lower(*args).compile().memory_analysis()
+            cache[name, kv_dtype] = fn.lower(*args).compile()
         return cache[name, kv_dtype]
     return get
+
+
+@pytest.fixture(scope="module")
+def memory(compiled):
+    """``memory_analysis()`` of each program."""
+    return lambda name, kv_dtype="fp": compiled(name, kv_dtype).memory_analysis()
 
 
 @pytest.mark.parametrize("name", PROGRAMS)
@@ -116,6 +122,23 @@ def test_layer_scan_updates_pool_in_place(programs, memory, name, kv_dtype):
     slack = 0 if kv_dtype == "fp" else 0.001 * pool
     assert pool <= ma.alias_size_in_bytes <= pool + slack, (name, kv_dtype, ma)
     assert ma.temp_size_in_bytes <= POOL_TEMP_SHARE * pool, (name, kv_dtype, ma)
+
+
+@pytest.mark.parametrize("name", ["decode", "mixed", "mixed_chunked"])
+def test_decode_attention_reads_pages_through_the_kernel(programs, compiled,
+                                                         name):
+    """The served programs with decode rows attend through the Pallas
+    decode kernel, which reads each row's live pages in place: no op
+    gathers the decode rows' full tables ``[max_batch x max_pages_per_seq,
+    page_size, KV_p x hd]`` out of the pool."""
+    serve = chip_smoke.serve_config()
+    _, args = programs(name)
+    row = args[2].shape[-1]
+    table = (f"bf16[{serve.max_batch * serve.max_pages_per_seq},"
+             f"{serve.page_size},{row}]")
+    text = compiled(name).as_text()
+    assert "tpu_custom_call" in text
+    assert table not in text
 
 
 def test_flash_attention_kernel_compiles_at_opt125m_width(on_chip):

@@ -111,7 +111,7 @@ def test_mixed_and_decode_write_in_place(setup, kv_dtype):
     cfg, params, tokens = setup
     int8 = kv_dtype == "int8"
     serve = ServeConfig(page_size=PS, n_pages=N_PAGES, kv_dtype=kv_dtype)
-    progs = build_step_programs(cfg, kv_dtype)
+    progs = build_step_programs(cfg, kv_dtype, "cpu")
     kpg, vpg = _random_pool(init_pool(cfg, serve, N_PAGES, jnp.bfloat16), 5)
     ref = _reference_kv(cfg, params, tokens, int8)
 

@@ -160,9 +160,16 @@ def test_chunked_live_rows_are_the_packed_tokens(setup):
         assert 0 < a["rows_live"] <= a["rows_program"] == shape_rows
         assert a["rows_live"] == sum(n for _, n in a["chunks"]) + len(a["decodes"])
         assert len(a["rids"]) == len(a["chunks"]) + len(a["decodes"])
+        assert a["decode_pages"] == sum(-(-(n + 1) // BASE.page_size)
+                                        for n in a["decodes"])
     assert (m.rows_live, m.rows_program) == (
         live, sum(d.attrs["rows_program"] for d in disp))
     assert m.summary()["row_use"] == pytest.approx(live / m.rows_program)
+    pages = sum(d.attrs["decode_pages"] for d in disp)
+    table = len(disp) * BASE.max_batch * BASE.max_pages_per_seq
+    assert 0 < pages < table
+    assert (m.decode_pages_live, m.decode_pages_table) == (pages, table)
+    assert m.summary()["decode_page_share"] == pytest.approx(pages / table)
     # every prompt token is a live prefill row exactly once
     assert sum(n for d in disp for _, n in d.attrs["chunks"]) == \
         m.n_prefill_tokens
